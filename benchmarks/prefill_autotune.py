@@ -63,7 +63,7 @@ def model_candidate(chunk, ctx, n_pg, ps, H, KVH, d, block_q, itemsize=4):
 def time_candidate(q, pool, bt, start, lens, block_q, repeats):
     fn = jax.jit(lambda q_: pp.paged_prefill_attend(
         q_, pool["k_pages"], pool["v_pages"], bt, start, lens,
-        block_q=block_q, interpret=True))
+        block_q=block_q))
     fn(q).block_until_ready()                                     # warm
     best = float("inf")
     for _ in range(repeats):

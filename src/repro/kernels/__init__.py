@@ -1,3 +1,17 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels: ``ops`` holds the jit'd entry points, ``ref`` the
+pure-jnp oracles each kernel is tested against."""
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+
+
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """The Pallas ``interpret`` flag of every kernel in this package: as
+    given, else True exactly when JAX's default backend is the CPU. This
+    is the one place the choice is made, so on an accelerator every kernel
+    compiles for it, and on the CPU it runs in the Pallas interpreter."""
+    if interpret is None:
+        return jax.default_backend() == "cpu"
+    return bool(interpret)

@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 _NEG = -1e30
 
 
@@ -56,7 +58,7 @@ def decode_attention_partials(q: jnp.ndarray, k_cache: jnp.ndarray,
                               softcap: Optional[float] = None,
                               scale: Optional[float] = None,
                               block_k: int = 512,
-                              interpret: bool = False):
+                              interpret: Optional[bool] = None):
     """q: (B, H, d); caches: (B, S, KVH, d).
 
     Returns partials (m, l, o) with a leading kv-split dim for the LSE
@@ -96,6 +98,6 @@ def decode_attention_partials(q: jnp.ndarray, k_cache: jnp.ndarray,
             jax.ShapeDtypeStruct((B * KVH, n_s, G), jnp.float32),
             jax.ShapeDtypeStruct((B * KVH, n_s, G, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qf, kf, vf)
     return m, lse, o

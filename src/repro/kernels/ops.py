@@ -1,7 +1,9 @@
 """jit'd wrappers over the Pallas kernels (+ pure-jnp combines).
 
-``interpret=True`` runs kernel bodies on CPU (how this container validates
-them); on real TPU deployments pass ``interpret=False``.
+``interpret`` defaults to None: ``repro.kernels.resolve_interpret`` then
+picks the Pallas interpreter on the CPU backend and compiles the kernel for
+the accelerator everywhere else. Tests pass ``interpret=True`` to run a
+kernel body on the CPU explicitly.
 
 **Profiling hook** (``set_profile_hook``): an opt-in callback wrapped
 around every public entry point, fed the kind, the post-
@@ -63,14 +65,14 @@ def _profiled(kind, fn, *args, **kw):
                                              "interpret"))
 def _flash_attention_jit(q, k, v, *, causal=True, window=None, softcap=None,
                          scale=None, block_q=128, block_k=128,
-                         interpret=False):
+                         interpret=None):
     return _fa.flash_attention(q, k, v, causal=causal, window=window,
                                softcap=softcap, scale=scale, block_q=block_q,
                                block_k=block_k, interpret=interpret)
 
 
 def flash_attention(q, k, v, *, causal=True, window=None, softcap=None,
-                    scale=None, block_q=128, block_k=128, interpret=False):
+                    scale=None, block_q=128, block_k=128, interpret=None):
     return _profiled("flash_attention", _flash_attention_jit, q, k, v,
                      causal=causal, window=window, softcap=softcap,
                      scale=scale, block_q=block_q, block_k=block_k,
@@ -80,7 +82,7 @@ def flash_attention(q, k, v, *, causal=True, window=None, softcap=None,
 @functools.partial(jax.jit, static_argnames=("softcap", "scale", "block_k",
                                              "interpret"))
 def _decode_attention_jit(q, k_cache, v_cache, *, softcap=None, scale=None,
-                          block_k=512, interpret=False):
+                          block_k=512, interpret=None):
     """Flash-decode: partials from the kernel, LSE combine in jnp.
 
     q: (B,H,d); caches (B,S,KVH,d) -> (B,H,d).
@@ -100,7 +102,7 @@ def _decode_attention_jit(q, k_cache, v_cache, *, softcap=None, scale=None,
 
 
 def decode_attention(q, k_cache, v_cache, *, softcap=None, scale=None,
-                     block_k=512, interpret=False):
+                     block_k=512, interpret=None):
     """Flash-decode: partials from the kernel, LSE combine in jnp.
 
     q: (B,H,d); caches (B,S,KVH,d) -> (B,H,d).
@@ -119,11 +121,11 @@ def _paged_decode_one(q, k_pages, v_pages, block_table, seq_lens, *,
         q, k_pages, v_pages, block_table, seq_lens,
         k_scale_pages=k_scale_pages, v_scale_pages=v_scale_pages,
         softcap=softcap, window=window, scale=scale, interpret=interpret)
-    m_glob = m.max(axis=2, keepdims=True)                   # (B,KVH,1,G)
+    m_glob = m.max(axis=1, keepdims=True)                   # (B,1,KVH,G,1)
     w = jnp.exp(m - m_glob)
-    l_glob = (lse * w).sum(axis=2)                          # (B,KVH,G)
-    o_glob = (o * w[..., None]).sum(axis=2)                 # (B,KVH,G,d)
-    out = o_glob / jnp.maximum(l_glob, 1e-30)[..., None]
+    l_glob = (lse * w).sum(axis=1)                          # (B,KVH,G,1)
+    o_glob = (o * w).sum(axis=1)                            # (B,KVH,G,d)
+    out = o_glob / jnp.maximum(l_glob, 1e-30)
     return out.reshape(B, H, d).astype(q.dtype)
 
 
@@ -132,7 +134,7 @@ def _paged_decode_one(q, k_pages, v_pages, block_table, seq_lens, *,
 def _paged_decode_attention_jit(q, k_pages, v_pages, block_table, seq_lens, *,
                                 k_scale_pages=None, v_scale_pages=None,
                                 softcap=None, window=None, scale=None,
-                                interpret=False):
+                                interpret=None):
     return _paged_decode_one(q, k_pages, v_pages, block_table, seq_lens,
                              k_scale_pages=k_scale_pages,
                              v_scale_pages=v_scale_pages, softcap=softcap,
@@ -142,7 +144,7 @@ def _paged_decode_attention_jit(q, k_pages, v_pages, block_table, seq_lens, *,
 def paged_decode_attention(q, k_pages, v_pages, block_table, seq_lens, *,
                            k_scale_pages=None, v_scale_pages=None,
                            softcap=None, window=None, scale=None,
-                           interpret=False):
+                           interpret=None):
     """Paged flash-decode: per-page partials from the kernel, LSE combine
     in jnp (same structure as ``decode_attention``).
 
@@ -162,7 +164,7 @@ def _paged_decode_attention_sharded_jit(q, k_pages, v_pages, block_table,
                                         seq_lens, *, k_scale_pages=None,
                                         v_scale_pages=None, softcap=None,
                                         window=None, scale=None,
-                                        interpret=False):
+                                        interpret=None):
     """Shard-group paged flash-decode: pools carry a leading shard axis
     (tp, P, ps, KVH/tp, d) and the kernel is invoked once per shard on
     that shard's query-head slice of ``q`` (B, H, d); the head-axis concat
@@ -188,7 +190,7 @@ def paged_decode_attention_sharded(q, k_pages, v_pages, block_table,
                                    seq_lens, *, k_scale_pages=None,
                                    v_scale_pages=None, softcap=None,
                                    window=None, scale=None,
-                                   interpret=False):
+                                   interpret=None):
     """Shard-group paged flash-decode (see ``_paged_decode_attention_sharded_jit``
     for the shard/head-slice structure)."""
     return _profiled("paged_decode_attention_sharded",
@@ -218,7 +220,7 @@ def prefill_tuning_key(H: int, d: int, KVH: int, chunk: int,
 def register_prefill_tuning(table: Dict[str, Dict]) -> Dict[str, Dict]:
     """Install autotuned prefill configs ({key: {"block_q": int, ...}});
     returns the previous table. Entries merge over defaults — an unknown
-    key falls back to block_q=min(chunk, 128)."""
+    key falls back to ``paged_prefill.default_block_q``."""
     global _PREFILL_TUNE, _PREFILL_TUNE_LOADED
     prev = _PREFILL_TUNE
     _PREFILL_TUNE = dict(table)
@@ -232,18 +234,15 @@ def _prefill_tuned_block_q(H, d, KVH, chunk, page_size) -> int:
         _PREFILL_TUNE_LOADED = True
         path = os.environ.get("REPRO_PREFILL_TUNE", _PREFILL_TUNE_DEFAULT_PATH)
         if path and os.path.exists(path):
-            try:
-                with open(path) as f:
-                    _PREFILL_TUNE.update(json.load(f).get("entries", {}))
-            except (OSError, ValueError):
-                pass
+            with open(path) as f:
+                _PREFILL_TUNE.update(json.load(f)["entries"])
     entry = _PREFILL_TUNE.get(prefill_tuning_key(H, d, KVH, chunk, page_size))
     if entry and "block_q" in entry:
         # clamp to the chunk width: speculative verify reuses this path at
         # chunk = spec_k + 1 (a handful of rows), and a stale or hand-edited
         # tune entry must never produce a query tile wider than the array
         return min(int(entry["block_q"]), chunk)
-    return min(chunk, 128)
+    return _pp.default_block_q(H, chunk)
 
 
 def _paged_prefill_one(q, k_new, v_new, pool, block_table, start, chunk_lens,
@@ -271,7 +270,7 @@ def _paged_prefill_one(q, k_new, v_new, pool, block_table, start, chunk_lens,
                                              "scale", "block_q", "interpret"))
 def _paged_prefill_jit(q, k_new, v_new, pool, block_table, start, chunk_lens,
                        *, quant=None, softcap=None, window=None, scale=None,
-                       block_q=None, interpret=False):
+                       block_q=None, interpret=None):
     return _paged_prefill_one(q, k_new, v_new, pool, block_table, start,
                               chunk_lens, quant=quant, softcap=softcap,
                               window=window, scale=scale, block_q=block_q,
@@ -280,7 +279,7 @@ def _paged_prefill_jit(q, k_new, v_new, pool, block_table, start, chunk_lens,
 
 def paged_prefill(q, k_new, v_new, pool, block_table, start, chunk_lens, *,
                   quant=None, softcap=None, window=None, scale=None,
-                  block_q=None, interpret=False):
+                  block_q=None, interpret=None):
     """Fused chunked prefill: scatter the chunk's K/V directly into the
     pool pages (no contiguous intermediate, no post-hoc ``write_prefill``
     copy), then flash-attend prefix+chunk from the pages.
@@ -306,7 +305,7 @@ def paged_prefill(q, k_new, v_new, pool, block_table, start, chunk_lens, *,
 def _paged_prefill_sharded_jit(q, k_new, v_new, pool, block_table, start,
                                chunk_lens, *, quant=None, softcap=None,
                                window=None, scale=None, block_q=None,
-                               interpret=False):
+                               interpret=None):
     """Shard-group fused prefill: pool leaves carry a leading shard axis
     (tp, P, ps, KVH/tp, d); shard ``s`` runs the write+attend pair on its
     query/kv head slices and the head-axis concat is the group's
@@ -334,7 +333,7 @@ def _paged_prefill_sharded_jit(q, k_new, v_new, pool, block_table, start,
 def paged_prefill_sharded(q, k_new, v_new, pool, block_table, start,
                           chunk_lens, *, quant=None, softcap=None,
                           window=None, scale=None, block_q=None,
-                          interpret=False):
+                          interpret=None):
     """Shard-group fused chunked prefill (see ``_paged_prefill_sharded_jit``
     for the shard/head-slice structure)."""
     if block_q is None:
@@ -348,7 +347,7 @@ def paged_prefill_sharded(q, k_new, v_new, pool, block_table, start,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def _ssd_jit(x, dt, A, Bm, Cm, *, chunk=128, h0=None, interpret=False):
+def _ssd_jit(x, dt, A, Bm, Cm, *, chunk=128, h0=None, interpret=None):
     """Full SSD forward via the intra-chunk kernel + jnp inter-chunk scan.
 
     Same contract as ``repro.models.ssm.ssd_chunked``:
@@ -390,7 +389,7 @@ def _ssd_jit(x, dt, A, Bm, Cm, *, chunk=128, h0=None, interpret=False):
     return y.astype(x.dtype), h_final
 
 
-def ssd(x, dt, A, Bm, Cm, *, chunk=128, h0=None, interpret=False):
+def ssd(x, dt, A, Bm, Cm, *, chunk=128, h0=None, interpret=None):
     """Full SSD forward via the intra-chunk kernel + jnp inter-chunk scan.
 
     Same contract as ``repro.models.ssm.ssd_chunked``:

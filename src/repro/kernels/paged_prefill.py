@@ -34,22 +34,26 @@ intermediate:
     Flash attention (online-softmax, same scratch discipline as
     ``flash_attention``) where **all** K/V — prefix and chunk — stream
     from the pool pages via the block table, after the write kernel has
-    landed the chunk. Grid (B, KVH, q_blocks, pages_per_seq) with the page
-    axis innermost and sequential; running max / sum / accumulator live in
-    VMEM scratch. Masking is absolute-position causal
-    (``k_pos <= start + q_row`` and ``k_pos < start + chunk_len``) plus
-    the optional sliding window; quantised pools dequantise in-kernel from
-    the scale planes. Pages wholly outside a q block's visible range are
-    skipped (causal skip, window skip, past-the-end skip).
+    landed the chunk. Grid (B, q_blocks, pages_per_seq) with the page axis
+    innermost and sequential. Each step fetches one whole page — all KV
+    heads: the TPU lowering tiles a block's last two dimensions, which are
+    (KVH, d) here, so a one-head slice of the pool is not a legal block —
+    and updates every query head's running max / sum / accumulator in
+    VMEM scratch. Queries enter head-major, (B, KVH, G, S, d), so each
+    head's (block_q, d) tile is a plain block. Masking is absolute-position
+    causal (``k_pos <= start + q_row`` and ``k_pos < start + chunk_len``)
+    plus the optional sliding window; quantised pools dequantise in-kernel
+    from the scale planes. Pages wholly outside a q block's visible range
+    are skipped (causal skip, window skip, past-the-end skip).
 
 Run order matters: attend reads the chunk's K/V *from the pages*, so the
 write kernel must run first. That ordering is also what makes the
 quantised paths bit-identical to the XLA write-then-gather reference —
 chunk tokens go through the same quantise→dequantise roundtrip on both.
 
-Target is TPU; correctness on this CPU-only container is established in
-interpret mode against ``repro.kernels.ref.paged_prefill_attention_ref``
-(see tests/test_paged_prefill.py).
+Both kernels compile for TPU v5e at published widths
+(tests/test_chip_compile.py); on the CPU backend they run in interpret
+mode against ``repro.kernels.ref`` (tests/test_paged_prefill.py).
 """
 from __future__ import annotations
 
@@ -61,6 +65,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 _NEG = -1e30
 _FP8_MAX = 448.0  # float8_e4m3 largest finite value
@@ -129,7 +135,7 @@ def paged_prefill_write(k_new: jnp.ndarray, v_new: jnp.ndarray,
                         k_scale_pages: Optional[jnp.ndarray] = None,
                         v_scale_pages: Optional[jnp.ndarray] = None,
                         quant: Optional[str] = None,
-                        interpret: bool = False):
+                        interpret: Optional[bool] = None):
     """Scatter a ragged batch of prompt chunks into their pool pages.
 
     k_new/v_new: (B, S, KVH, d) — rows past ``chunk_lens[b]`` are padding
@@ -185,7 +191,7 @@ def paged_prefill_write(k_new: jnp.ndarray, v_new: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=out_shape,
         input_output_aliases=aliases,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(block_table.astype(jnp.int32), start.astype(jnp.int32),
       chunk_lens.astype(jnp.int32), *args)
     pool = {"k_pages": outs[0], "v_pages": outs[1]}
@@ -199,6 +205,14 @@ def paged_prefill_write(k_new: jnp.ndarray, v_new: jnp.ndarray,
 # attend kernel: chunk queries vs paged prefix+chunk K/V (online softmax)
 # ---------------------------------------------------------------------------
 
+def default_block_q(n_heads: int, chunk: int) -> int:
+    """Query rows per attend block when no tuned value exists: at most 128,
+    and at most 4096 // n_heads so the per-query-head softmax scratch
+    (n_heads x block_q rows of max, sum and accumulator) fits the default
+    scoped VMEM of a TPU v5e core at head_dim 128."""
+    return min(chunk, 128, max(8, 4096 // n_heads))
+
+
 def _pa_kernel(bt_ref, start_ref, lens_ref, q_ref, k_ref, v_ref, *refs,
                scale: float, softcap: Optional[float],
                window: Optional[int], page_size: int, block_q: int,
@@ -209,9 +223,10 @@ def _pa_kernel(bt_ref, start_ref, lens_ref, q_ref, k_ref, v_ref, *refs,
         ks_ref = vs_ref = None
         o_ref, m_scr, l_scr, acc_scr = refs
     b = pl.program_id(0)
-    qi = pl.program_id(2)
-    pi = pl.program_id(3)
-    n_pg = pl.num_programs(3)
+    qi = pl.program_id(1)
+    pi = pl.program_id(2)
+    n_pg = pl.num_programs(2)
+    KVH, G = q_ref.shape[1], q_ref.shape[2]
 
     @pl.when(pi == 0)
     def _init():
@@ -222,12 +237,8 @@ def _pa_kernel(bt_ref, start_ref, lens_ref, q_ref, k_ref, v_ref, *refs,
     st = start_ref[b]
     ln = lens_ref[b]
     total = st + ln                           # tokens 0..total-1 are live
-    G, d = q_ref.shape[3], q_ref.shape[4]
-
-    # absolute query position per flattened (q_row, group) scratch row
-    row = jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, G), 0).reshape(block_q * G, 1)
-    q_abs = st + qi * block_q + row                       # (bq*G, 1)
+    q_abs = st + qi * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, 1), 0)                       # (bq, 1)
     k_pos = pi * page_size + jax.lax.broadcasted_iota(
         jnp.int32, (1, page_size), 1)
 
@@ -243,38 +254,46 @@ def _pa_kernel(bt_ref, start_ref, lens_ref, q_ref, k_ref, v_ref, *refs,
 
     @pl.when(needed)
     def _compute():
-        q = q_ref[0, :, 0].astype(jnp.float32).reshape(block_q * G, d)
-        k = k_ref[0, :, 0].astype(jnp.float32)            # (ps, d)
-        if quant:
-            k = k * ks_ref[0, :, 0].astype(jnp.float32)[:, None]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if softcap is not None:
-            s = softcap * jnp.tanh(s / softcap)
         ok = jnp.logical_and(k_pos <= q_abs, k_pos < total)
         if window is not None:
             ok = jnp.logical_and(ok, q_abs - k_pos < window)
-        s = jnp.where(ok, s, _NEG)                        # (bq*G, ps)
+        for h in range(KVH):
+            k = k_ref[0, :, h, :].astype(jnp.float32)     # (ps, d)
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            if quant:
+                k = k * ks_ref[0, :, h:h + 1]
+                v = v * vs_ref[0, :, h:h + 1]
 
-        m_prev = m_scr[...]
-        l_prev = l_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_scr[...] = l_prev * alpha + p.sum(axis=-1)
-        m_scr[...] = m_new
-        v = v_ref[0, :, 0].astype(jnp.float32)            # (ps, d)
-        if quant:
-            v = v * vs_ref[0, :, 0].astype(jnp.float32)[:, None]
-        pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + pv
+            # the G query heads of KV head h share its K/V tile; a loop
+            # rather than an unrolled body keeps the kernel's compile short
+            def one_query_head(g, carry, h=h, k=k, v=v):
+                r = h * G + g                             # query head
+                q = q_ref[0, h, g].astype(jnp.float32)    # (bq, d)
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                if softcap is not None:
+                    s = softcap * jnp.tanh(s / softcap)
+                s = jnp.where(ok, s, _NEG)                # (bq, ps)
+                m_prev = m_scr[r]                         # (bq, 1)
+                m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                p = jnp.exp(s - m_new)
+                l_scr[r] = l_scr[r] * alpha + p.sum(axis=-1, keepdims=True)
+                m_scr[r] = m_new
+                pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+                                         preferred_element_type=jnp.float32)
+                acc_scr[r] = acc_scr[r] * alpha + pv
+                return carry
 
+            jax.lax.fori_loop(0, G, one_query_head, 0)
     @pl.when(pi == n_pg - 1)
     def _finalize():
-        denom = jnp.maximum(l_scr[...], 1e-30)
-        out = (acc_scr[...] / denom[:, None]).astype(o_ref.dtype)
-        o_ref[0, :, 0] = out.reshape(block_q, G, d)
+        for h in range(KVH):
+            for g in range(G):
+                r = h * G + g
+                denom = jnp.maximum(l_scr[r], 1e-30)
+                o_ref[0, h, g] = (acc_scr[r] / denom).astype(o_ref.dtype)
 
 
 def paged_prefill_attend(q: jnp.ndarray, k_pages: jnp.ndarray,
@@ -286,7 +305,7 @@ def paged_prefill_attend(q: jnp.ndarray, k_pages: jnp.ndarray,
                          window: Optional[int] = None,
                          scale: Optional[float] = None,
                          block_q: Optional[int] = None,
-                         interpret: bool = False) -> jnp.ndarray:
+                         interpret: Optional[bool] = None) -> jnp.ndarray:
     """Chunk queries attend the paged prefix+chunk K/V. Call *after*
     ``paged_prefill_write`` — the chunk's own K/V stream from the pages.
 
@@ -300,47 +319,48 @@ def paged_prefill_attend(q: jnp.ndarray, k_pages: jnp.ndarray,
     n_pg = block_table.shape[1]
     G = H // KVH
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    block_q = min(block_q or 128, S)
+    block_q = min(block_q or default_block_q(H, S), S)
     quant = k_scale_pages is not None
 
     pq = (-S) % block_q
     qp = jnp.pad(q, ((0, 0), (0, pq), (0, 0), (0, 0))) if pq else q
-    qr = qp.reshape(B, S + pq, KVH, G, d)
+    # head-major queries: (B, KVH, G, S, d)
+    qr = qp.reshape(B, S + pq, KVH, G, d).transpose(0, 2, 3, 1, 4)
     n_qb = (S + pq) // block_q
 
     kernel = functools.partial(_pa_kernel, scale=scale, softcap=softcap,
                                window=window, page_size=page_size,
                                block_q=block_q, quant=quant)
-    q_spec = pl.BlockSpec((1, block_q, 1, G, d),
-                          lambda b, h, qi, i, bt, st, ln: (b, qi, h, 0, 0))
-    page_spec = pl.BlockSpec((1, page_size, 1, d),
-                             lambda b, h, qi, i, bt, st, ln:
-                             (bt[b, i], 0, h, 0))
+    q_spec = pl.BlockSpec((1, KVH, G, block_q, d),
+                          lambda b, qi, i, bt, st, ln: (b, 0, 0, qi, 0))
+    page_spec = pl.BlockSpec((1, page_size, KVH, d),
+                             lambda b, qi, i, bt, st, ln:
+                             (bt[b, i], 0, 0, 0))
     in_specs = [q_spec, page_spec, page_spec]
     args = [qr, k_pages, v_pages]
     if quant:
-        scale_spec = pl.BlockSpec((1, page_size, 1),
-                                  lambda b, h, qi, i, bt, st, ln:
-                                  (bt[b, i], 0, h))
+        scale_spec = pl.BlockSpec((1, page_size, KVH),
+                                  lambda b, qi, i, bt, st, ln:
+                                  (bt[b, i], 0, 0))
         in_specs += [scale_spec, scale_spec]
         args += [k_scale_pages, v_scale_pages]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, KVH, n_qb, n_pg),
+        grid=(B, n_qb, n_pg),
         in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((block_q * G,), jnp.float32),
-            pltpu.VMEM((block_q * G,), jnp.float32),
-            pltpu.VMEM((block_q * G, d), jnp.float32),
+            pltpu.VMEM((H, block_q, 1), jnp.float32),
+            pltpu.VMEM((H, block_q, 1), jnp.float32),
+            pltpu.VMEM((H, block_q, d), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, S + pq, KVH, G, d), q.dtype),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((B, KVH, G, S + pq, d), q.dtype),
+        interpret=resolve_interpret(interpret),
     )(block_table.astype(jnp.int32), start.astype(jnp.int32),
       chunk_lens.astype(jnp.int32), *args)
-    return out.reshape(B, S + pq, H, d)[:, :S]
+    return out.transpose(0, 3, 1, 2, 4).reshape(B, S + pq, H, d)[:, :S]

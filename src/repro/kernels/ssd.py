@@ -11,10 +11,13 @@ recurrence and the C_i*h_prev correction run as jnp in the wrapper
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 
 def _ssd_kernel(x_ref, b_ref, c_ref, cum_ref, dt_ref, y_ref, st_ref, *,
@@ -48,7 +51,7 @@ def _ssd_kernel(x_ref, b_ref, c_ref, cum_ref, dt_ref, y_ref, st_ref, *,
 
 def ssd_intra_chunk(x: jnp.ndarray, Bm: jnp.ndarray, Cm: jnp.ndarray,
                     cum: jnp.ndarray, dt: jnp.ndarray, *,
-                    interpret: bool = False):
+                    interpret: Optional[bool] = None):
     """x: (B,nc,Q,H,P)  Bm/Cm: (B,nc,Q,H,N) (pre-broadcast to heads)
     cum/dt: (B,nc,Q,H) float32.
 
@@ -81,7 +84,7 @@ def ssd_intra_chunk(x: jnp.ndarray, Bm: jnp.ndarray, Cm: jnp.ndarray,
             jax.ShapeDtypeStruct((B * nc * H, Q, P), x.dtype),
             jax.ShapeDtypeStruct((B * nc * H, N, P), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xf, bf, cf, cumf, dtf)
     y = jnp.moveaxis(y.reshape(B, nc, H, Q, P), 2, 3)
     st = st.reshape(B, nc, H, N, P)

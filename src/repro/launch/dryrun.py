@@ -28,15 +28,17 @@ from repro.configs.registry import ARCHS, get_arch, get_shape
 from repro.core.blueprint import suggest_plan
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import input_specs
+from repro.obs.profile import PLAN_DEVICE_KIND, device_peaks
 from repro.optim.adamw import OptimConfig
 from repro.train import steps as steps_mod
 
 # ---------------------------------------------------------------------------
-# roofline hardware constants (TPU v5e-class target)
+# roofline hardware constants: the planned chip's row of the one peaks table
 # ---------------------------------------------------------------------------
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
-HBM_BW = 819e9               # B/s per chip
-LINK_BW = 50e9               # B/s per ICI link
+_PEAKS = device_peaks(PLAN_DEVICE_KIND)
+PEAK_FLOPS = _PEAKS.bf16_flops       # bf16 FLOP/s per chip
+HBM_BW = _PEAKS.hbm_bw               # B/s per chip
+LINK_BW = _PEAKS.ici_bw / 4          # B/s per ICI link (4 per chip, 2-D torus)
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -128,17 +130,8 @@ def _lin_extrap(c1, c2, n_periods: int):
     return max(0.0, float(c1) + (n_periods - 1) * (float(c2) - float(c1)))
 
 
-def _cost_dict(compiled) -> Dict[str, Any]:
-    """compiled.cost_analysis() across jax versions: 0.4.x returns a list of
-    per-computation dicts, newer jax a single dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
-
-
 def _extract_costs(compiled) -> Dict[str, Any]:
-    cost = _cost_dict(compiled)
+    cost = compiled.cost_analysis()
     colls = parse_collectives(compiled.as_text())
     return {
         "flops": float(cost.get("flops", 0.0)),
@@ -268,7 +261,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     try:
         meas = measure_costs(cfg, shape, mesh, plan)
     except Exception as e:  # noqa: BLE001 - fall back to scanned numbers
-        cost = _cost_dict(compiled)
+        cost = compiled.cost_analysis()
         meas = {
             "flops": float(cost.get("flops", 0.0)),
             "bytes": float(cost.get("bytes accessed", 0.0)),
@@ -302,12 +295,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
             "argument_bytes": mem.argument_size_in_bytes,
             "output_bytes": mem.output_size_in_bytes,
             "temp_bytes": mem.temp_size_in_bytes,
-            # jax < 0.5 has no peak_memory_in_bytes; resident args +
-            # outputs + temps (minus donated aliases) is the same bound
-            "peak_bytes": getattr(
-                mem, "peak_memory_in_bytes",
-                mem.argument_size_in_bytes + mem.output_size_in_bytes
-                + mem.temp_size_in_bytes - mem.alias_size_in_bytes),
+            "peak_bytes": mem.peak_memory_in_bytes,
             "alias_bytes": mem.alias_size_in_bytes,
         },
         "cost": {"flops_per_device": flops_dev,

@@ -52,20 +52,32 @@ groups.
 ``--seed`` drives both parameter init and workload generation, so
 run-to-run variation studies are one flag.
 
-Both paths run the arch's reduced config on CPU; the full-config serve
-cells (decode_32k / long_500k) are lowered and analysed by the dry-run.
+By default both engines serve the arch's reduced config. ``--layers N``
+serves its published config instead (``get_arch``) cut only in depth, to
+its first N layers: every width as published, the whole vocabulary and
+head, float32 params as ``M.init`` makes them. N must be a whole number
+of ``layer_pattern`` periods. For qwen3-32b, ``--layers 2`` is ~10.1 GB
+of params: what one 16 GB TPU v5e chip holds next to its page pool
+(``chip_smoke.py`` serves it). The full-depth serve cells (decode_32k /
+long_500k) are lowered and analysed by the dry-run.
+
+Run as a program, the launcher keeps JAX's persistent compilation cache
+in ``$JAX_COMPILATION_CACHE_DIR`` when set, else in ``.jax_cache/`` at
+the checkout root (``repro.launch.compile_cache``).
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import time
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.registry import ARCHS, get_reduced
+from repro.configs.registry import ARCHS, get_arch, get_reduced
 from repro.models import model as M
 from repro.obs.metrics import percentile
 from repro.obs.trace import Tracer
@@ -99,7 +111,7 @@ def _finish_obs(out: dict, args, tracer, profiler, expose_fn,
         out["profile"] = profiler.summary()
 
 
-def run_static(cfg, params, args) -> dict:
+def run_static(cfg, params, args) -> Tuple[dict, list]:
     key = jax.random.PRNGKey(args.seed)
     B, S = args.batch, args.prompt_len
     batch = {"tokens": jax.random.randint(key, (B, S), 0, cfg.vocab_size)}
@@ -128,7 +140,7 @@ def run_static(cfg, params, args) -> dict:
         "prefill_tok_per_s": round(B * S / t_pre, 1),
         "decode_tok_per_s": round(B * args.gen / t_dec, 1),
         "generated": [[int(t) for t in row[:8]] for row in toks],
-    }
+    }, []
 
 
 def persona_workload(vocab_size, rng, personas, users, persona_len,
@@ -269,7 +281,7 @@ def _spec_stats(out: dict, args, stats) -> None:
         out[k] = stats[k]
 
 
-def run_fleet(cfg, params, args) -> dict:
+def run_fleet(cfg, params, args) -> Tuple[dict, list]:
     """Replicated fabric: k scheduler replicas behind one router."""
     from repro.serving.router import ServingRouter
     if not supports_paged(cfg):
@@ -321,6 +333,10 @@ def run_fleet(cfg, params, args) -> dict:
         "spillovers": fleet["spillovers"],
         "reroutes": fleet["reroutes"],
         "generated": [r.out_tokens[:8] for r in done[:4]],
+        # where each replica's params and page pool live (one device per
+        # replica on a multi-device host)
+        "replica_devices": {str(i): rep.devices()
+                            for i, rep in sorted(router.replicas.items())},
     }
     if args.chunked_prefill:
         out["chunked_prefill"] = args.chunked_prefill
@@ -335,10 +351,10 @@ def run_fleet(cfg, params, args) -> dict:
     if ctl is not None:
         out["autoscale"] = ctl.summary()
     _finish_obs(out, args, tracer, profiler, router.expose, ctl=ctl)
-    return out
+    return out, done
 
 
-def run_paged(cfg, params, args) -> dict:
+def run_paged(cfg, params, args) -> Tuple[dict, list]:
     if not supports_paged(cfg):
         raise SystemExit(f"{cfg.name}: use --engine static (MLA/enc-dec)")
     rng = np.random.RandomState(args.seed)
@@ -398,12 +414,19 @@ def run_paged(cfg, params, args) -> dict:
     if ctl is not None:
         out["autoscale"] = ctl.summary()
     _finish_obs(out, args, tracer, profiler, sched.registry.expose, ctl=ctl)
-    return out
+    return out, done
 
 
-def main() -> None:
+def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
+    """Parse and validate launcher arguments (``argv`` None: sys.argv)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=sorted(ARCHS))
+    ap.add_argument("--layers", type=int, default=None, metavar="N",
+                    help="serve the arch's published config cut to its "
+                    "first N layers (a whole number of layer_pattern "
+                    "periods): every width as published, the whole "
+                    "vocabulary, float32 params; the only cut is depth. "
+                    "Default: the reduced config")
     ap.add_argument("--engine", default="static",
                     choices=("static", "paged"))
     ap.add_argument("--batch", type=int, default=4,
@@ -509,7 +532,14 @@ def main() -> None:
                     "and report modeled FLOPs/bytes + roofline fractions "
                     "in the result JSON (read-only; tokens unchanged)")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    if args.layers is not None:
+        full = get_arch(args.arch)
+        period = len(full.layer_pattern)
+        if not 1 <= args.layers <= full.n_layers or args.layers % period:
+            ap.error(f"--layers must be a multiple of {full.name}'s "
+                     f"layer_pattern period ({period}) in [1, "
+                     f"{full.n_layers}]")
     if args.autoscale and args.engine != "paged":
         ap.error("--autoscale requires --engine paged")
     for flag, val in (("--events-out", args.events_out),
@@ -574,18 +604,40 @@ def main() -> None:
             _parse_tenants(args.tenants)
         except ValueError as e:
             ap.error(str(e))
+    return args
 
-    cfg = get_reduced(args.arch)
-    params = M.init(cfg, jax.random.PRNGKey(args.seed))
+
+def model_config(args: argparse.Namespace):
+    """The config ``args`` serve: the reduced one, or with ``--layers N``
+    the published one cut to its first N layers."""
+    if args.layers is None:
+        return get_reduced(args.arch)
+    return dataclasses.replace(get_arch(args.arch), n_layers=args.layers)
+
+
+def serve(args: argparse.Namespace, params=None) -> Tuple[dict, list]:
+    """Serve the workload ``args`` describe: (result dict, finished
+    requests; empty for the static engine). ``params`` are weights for
+    ``model_config(args)`` made by the caller; by default the launcher
+    makes them with ``M.init`` from ``--seed``."""
+    cfg = model_config(args)
+    if params is None:
+        params = M.init(cfg, jax.random.PRNGKey(args.seed))
     if args.engine != "paged":
         runner = run_static
     elif args.replicas > 1:
         runner = run_fleet
     else:
         runner = run_paged
-    out = runner(cfg, params, args)
+    return runner(cfg, params, args)
+
+
+def main(argv: Optional[List[str]] = None) -> None:
+    out, _ = serve(parse_args(argv))
     print(json.dumps(out))
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

@@ -491,8 +491,6 @@ def _gqa_paged_decode_shard_map(cfg, p, x, cos, sin, cache, seq_lens,
     replicated output projection."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.parallel.collectives import shard_map_compat
-
     tp, ax = shard.tp, shard.axis
     B = x.shape[0]
     Hs = cfg.n_heads // tp
@@ -519,11 +517,11 @@ def _gqa_paged_decode_shard_map(cfg, p, x, cos, sin, cache, seq_lens,
         o = jax.lax.all_gather(o_s, ax, axis=2, tiled=True)  # (B,1,H,hd)
         return o, {k: v[None] for k, v in pool_s.items()}
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body, mesh=shard.mesh,
         in_specs=(sliced_specs, {k: P() for k in repl}, pool_specs,
                   P(), P(), P(), P(), P()),
-        out_specs=(P(), pool_specs))
+        out_specs=(P(), pool_specs), check_vma=False)
     o, new_cache = fn(sliced, repl, cache, x, cos, sin, seq_lens,
                       block_table.astype(jnp.int32))
     y = o.reshape(B, 1, -1) @ p["wo"].astype(x.dtype)
@@ -624,8 +622,7 @@ def _paged_prefill_write_attend(cfg: ModelConfig, pool: Dict[str, jnp.ndarray],
         from repro.kernels import ops as kops
         o, new_pool = kops.paged_prefill(
             q, k_new, v_new, pool, block_table, start, chunk_len,
-            quant=mode, softcap=cfg.attn_softcap, window=window,
-            interpret=True)
+            quant=mode, softcap=cfg.attn_softcap, window=window)
         return o.astype(dt), new_pool
     ps = pool["k_pages"].shape[1]
     n_pg = block_table.shape[1]

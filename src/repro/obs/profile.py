@@ -7,11 +7,13 @@ from the active-parameter count and bytes-moved from the weight +
 paged-KV traffic the call implies. Dividing modeled work by the machine
 peaks gives a roofline-utilization fraction per kernel kind:
 
-    frac = max(flops / PEAK_FLOPS, bytes / HBM_BW) / wall_seconds
+    frac = max(flops / peak_flops, bytes / hbm_bw) / wall_seconds
 
 i.e. how close the call came to the speed-of-light time its heavier
-bottleneck allows (1.0 = on the roofline; CPU interpret-mode runs will
-sit far below it, which is itself the point of reporting the fraction).
+bottleneck allows (1.0 = on the roofline). The peaks are the published
+figures of the device the process runs on, looked up by its
+``device_kind`` in ``DEVICE_PEAKS``; a kind missing from the table
+raises. The CPU backend has no such peaks, so there the fraction is None.
 
 Two attachment styles:
 
@@ -27,21 +29,54 @@ emit byte-identical tokens (same contract as tracing).
 """
 from __future__ import annotations
 
+import dataclasses
 import time
 from typing import Any, Callable, Dict, Optional
 
 import jax
 
-__all__ = ["KernelProfiler", "PEAK_FLOPS", "HBM_BW", "PCIE_BW",
+__all__ = ["KernelProfiler", "DevicePeaks", "DEVICE_PEAKS", "device_peaks",
+           "PLAN_DEVICE_KIND", "PEAK_FLOPS", "HBM_BW", "PCIE_BW",
            "PCIE_LATENCY"]
 
-# Modeled accelerator peaks (bf16 FLOPs, HBM bytes/s). These mirror the
-# planning constants in repro/launch/dryrun.py — duplicated here rather
-# than imported because dryrun sets XLA_FLAGS to force a 512-device host
-# platform at import time, which must never happen as a side effect of
-# turning profiling on.
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks of one accelerator kind."""
+    bf16_flops: float        # FLOP/s
+    hbm_bw: float            # HBM bytes/s
+    ici_bw: float            # chip-to-chip interconnect bytes/s, all links
+    source: str
+
+
+# The one table of device peaks, keyed by ``jax.Device.device_kind``. The
+# profiler, the dry-run roofline and the modeled cost terms all read it.
+DEVICE_PEAKS: Dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(
+        bf16_flops=197e12, hbm_bw=819e9, ici_bw=1600e9 / 8,
+        source='Google Cloud documentation, "TPU v5e": per chip 197 '
+               'TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s, '
+               '1,600 Gbit/s interchip interconnect'),
+}
+
+
+def device_peaks(device_kind: str) -> DevicePeaks:
+    """Peaks of ``device_kind``. A kind missing from ``DEVICE_PEAKS`` is
+    an error, never a default: a number divided by the wrong chip's peak
+    would read as a real utilization."""
+    if device_kind not in DEVICE_PEAKS:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; add it to "
+            f"DEVICE_PEAKS with its source (known: {sorted(DEVICE_PEAKS)})")
+    return DEVICE_PEAKS[device_kind]
+
+
+# The chip that modeled (not measured) cost terms plan for: the dry-run
+# roofline, the host tier's recompute-vs-transfer model and the benches'
+# roofline models. A TPU v5e.
+PLAN_DEVICE_KIND = "TPU v5 lite"
+PEAK_FLOPS = device_peaks(PLAN_DEVICE_KIND).bf16_flops
+HBM_BW = device_peaks(PLAN_DEVICE_KIND).hbm_bw
 # Modeled host<->device interconnect (PCIe gen4 x16-class): sustained
 # bytes/s plus a fixed per-transfer setup cost (DMA programming, host
 # pinning, completion interrupt). The serving host-RAM KV tier's
@@ -59,15 +94,18 @@ class KernelProfiler:
     2 * active_params FLOPs per generated token, weight bytes + paged-KV
     bytes per token of attended context. Without ``cfg`` only wall time
     and explicitly-passed work are recorded.
+
+    ``device`` (default ``jax.devices()[0]``) is the chip whose peaks the
+    roofline fraction divides by; a tp shard group counts ``tp`` of them.
     """
 
     def __init__(self, cfg: Any = None, *, tp: int = 1,
-                 dtype_bytes: int = 2, peak_flops: float = PEAK_FLOPS,
-                 hbm_bw: float = HBM_BW) -> None:
+                 dtype_bytes: int = 2, device: Any = None) -> None:
         self.cfg = cfg
         self.tp = max(1, int(tp))
-        self.peak_flops = float(peak_flops) * self.tp
-        self.hbm_bw = float(hbm_bw) * self.tp
+        dev = device if device is not None else jax.devices()[0]
+        self.peaks = (None if dev.platform == "cpu"
+                      else device_peaks(dev.device_kind))
         self.enabled = True
         self._param_bytes = 0.0
         self._active_params = 0.0
@@ -133,18 +171,23 @@ class KernelProfiler:
     def summary(self) -> Dict[str, Dict[str, float]]:
         """Per-kind totals plus the roofline-utilization fraction:
         modeled speed-of-light time (max of compute- and bandwidth-bound
-        times) over measured wall time."""
-        out: Dict[str, Dict[str, float]] = {}
+        times) over measured wall time; None on the CPU backend, which
+        has no published peaks."""
+        out: Dict[str, Dict[str, Any]] = {}
         for kind, b in sorted(self.records.items()):
             wall = b["wall_s"]
-            sol = max(b["modeled_flops"] / self.peak_flops,
-                      b["modeled_bytes"] / self.hbm_bw)
+            frac = None
+            if self.peaks is not None:
+                sol = max(b["modeled_flops"]
+                          / (self.peaks.bf16_flops * self.tp),
+                          b["modeled_bytes"] / (self.peaks.hbm_bw * self.tp))
+                frac = (sol / wall) if wall > 0 else 0.0
             out[kind] = {
                 "calls": int(b["calls"]),
                 "wall_s": wall,
                 "modeled_flops": b["modeled_flops"],
                 "modeled_bytes": b["modeled_bytes"],
-                "roofline_frac": (sol / wall) if wall > 0 else 0.0,
+                "roofline_frac": frac,
             }
         return out
 

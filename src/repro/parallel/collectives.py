@@ -21,24 +21,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """shard_map without replication checking, across jax versions: newest
-    jax spells it ``jax.shard_map(..., check_vma=)``, the 0.5-0.6 band has
-    ``jax.shard_map(..., check_rep=)``, and 0.4.x keeps it under
-    ``jax.experimental.shard_map`` with ``check_rep=``."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    except TypeError:   # top-level shard_map that still takes check_rep
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-
-
 def quantize_int8(x: jnp.ndarray, block: int = 256
                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Per-block symmetric int8 quantisation. Returns (q, scales)."""
@@ -93,9 +75,9 @@ def make_compressed_grad_sync(mesh, axis: str = "pod", block: int = 256,
     spec = leaf_spec if leaf_spec is not None else P(axis)
 
     def sync_leaf(g):
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             lambda t: compressed_psum(t, axis, block) / mesh.shape[axis],
-            mesh=mesh, in_specs=spec, out_specs=spec)
+            mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False)
         return fn(g)
 
     def sync(grads):

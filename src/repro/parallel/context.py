@@ -72,7 +72,7 @@ class ShardGroup:
     stay a single (logical) control plane — see docs/sharding.md.
 
     ``mesh`` is optional. With a mesh whose ``axis`` has size ``tp``, the
-    sharded decode step runs under ``shard_map_compat`` (one program per
+    sharded decode step runs under ``jax.shard_map`` (one program per
     device, the head-axis ``all_gather`` on the wire). Without one, the
     same per-shard body runs as an unrolled loop inside a single program —
     semantically identical, which is what makes the tp>1 vs tp=1

@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.parallel.collectives import shard_map_compat
-
 
 def gpipe_forward(stack_params: Any, x: jnp.ndarray, *,
                   body: Callable[[Any, jnp.ndarray], jnp.ndarray],
@@ -71,8 +69,8 @@ def gpipe_forward(stack_params: Any, x: jnp.ndarray, *,
         acc = jax.lax.psum(acc, axis) / 1.0
         return acc.reshape(x_rep.shape)
 
-    fn = shard_map_compat(stage_program, mesh=mesh,
-                          in_specs=(P(axis), P()), out_specs=P())
+    fn = jax.shard_map(stage_program, mesh=mesh, in_specs=(P(axis), P()),
+                       out_specs=P(), check_vma=False)
     return fn(stack_params, x)
 
 

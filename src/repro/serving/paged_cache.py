@@ -825,7 +825,9 @@ def migrate_pages(src_cache: Any, dst_cache: Any, src_pages: List[int],
     land bit-identically in a decode replica's pool. Partial trailing pages
     copy whole-page: unwritten slots are zeros on both sides. SSM slot
     state moves separately (``extract_ssm_slot`` / ``merge_ssm_slot``).
-    Runs eagerly — handoffs are per-request events between ticks.
+    Runs eagerly — handoffs are per-request events between ticks. The
+    two caches may sit on different devices (one device per replica): the
+    copied rows move to the destination leaf's device.
     """
     assert len(src_pages) == len(dst_pages)
     if not src_pages:
@@ -841,6 +843,8 @@ def migrate_pages(src_cache: Any, dst_cache: Any, src_pages: List[int],
                 if k not in dnode:
                     continue
                 rows = snode[k][lead + (src_ids,)]
+                if rows.devices() != dnode[k].devices():
+                    rows = jax.device_put(rows, dnode[k].sharding)
                 out[k] = dnode[k].at[lead + (dst_ids,)].set(
                     rows.astype(dnode[k].dtype))
             return out

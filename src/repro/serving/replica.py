@@ -16,6 +16,9 @@ needs to reason about a member:
   reserved by admitted streams plus the worst-case pages of everything in
   the replica's own queue, so routing sees committed-but-not-yet-admitted
   work too (pages are logical, so the signal is tp-invariant);
+* **device** — the one accelerator its params copy and page pool live on
+  (``build(device=...)``; the router assigns replica ``i`` device
+  ``i % n`` on an n-device host);
 * **lifecycle** — ``draining`` stops new routing while admitted/queued
   streams finish (graceful scale-in); ``failed`` marks a dead replica
   (heartbeat DEAD / spot preemption) whose unfinished streams the router
@@ -31,7 +34,9 @@ needs to reason about a member:
 """
 from __future__ import annotations
 
-from typing import List, Optional, Sequence
+from typing import Any, List, Optional, Sequence
+
+import jax
 
 from repro.serving.request import Request, worst_case_pages
 from repro.serving.scheduler import ContinuousBatchingScheduler
@@ -77,6 +82,13 @@ class ServingReplica:
     def tp(self) -> int:
         return self.sched.tp
 
+    def devices(self) -> List[str]:
+        """Every device that holds a leaf of this replica's params or page
+        pool, as JAX names it (one, under one-device-per-replica)."""
+        leaves = jax.tree_util.tree_leaves((self.sched.params,
+                                            self.sched.cache))
+        return sorted({str(d) for leaf in leaves for d in leaf.devices()})
+
     @classmethod
     def build(cls, cfg, params, replica_id: int, *, max_slots: int = 4,
               page_size: int = 16, num_pages: Optional[int] = None,
@@ -87,14 +99,17 @@ class ServingReplica:
               role: str = "mixed", spec_k: Optional[int] = None,
               spec_draft=None, host_pages: Optional[int] = None,
               tenant_quotas=None,
-              swap_crossover: Optional[int] = None) -> "ServingReplica":
+              swap_crossover: Optional[int] = None,
+              device: Any = None) -> "ServingReplica":
+        """``device``: the one device this replica's params copy and page
+        pool live on (None: JAX's default device)."""
         sched = ContinuousBatchingScheduler(
             cfg, params, max_slots=max_slots, page_size=page_size,
             num_pages=num_pages, max_seq_len=max_seq_len,
             prefix_cache=prefix_cache, tp=tp, prefill_budget=prefill_budget,
             role=role, spec_k=spec_k, spec_draft=spec_draft,
             host_pages=host_pages, tenant_quotas=tenant_quotas,
-            swap_crossover=swap_crossover)
+            swap_crossover=swap_crossover, device=device)
         return cls(replica_id, sched, hostname=hostname, hostnames=hostnames)
 
     # -------------------------------------------------------------- state --

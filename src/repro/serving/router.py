@@ -64,6 +64,8 @@ import collections
 import time
 from typing import Any, Deque, Dict, List, Optional, Sequence
 
+import jax
+
 from repro.configs.base import ModelConfig
 from repro.obs.metrics import (Histogram, MetricsRegistry, StatsView,
                                SECONDS_BUCKETS, TICK_BUCKETS)
@@ -109,6 +111,11 @@ class ServingRouter:
                 f"replicas ({replicas}) so both roles exist")
         self.cfg = cfg
         self.params = params
+        # one device per replica: replica i's params copy and page pool
+        # live on devices[i % n]; one copy of the params per device, shared
+        # by every replica placed there. On one device nothing moves.
+        self._devices = jax.devices()
+        self._device_params: Dict[Any, Any] = {}
         # tp > 1: every fabric member is a shard group — tp nodes, one
         # logical scheduler (placement entries become hostname *lists*)
         self.replica_kw = dict(max_slots=max_slots, page_size=page_size,
@@ -183,10 +190,19 @@ class ServingRouter:
         sizing — fleet members become heterogeneous the moment per-replica
         autoscalers resize them, so routing never assumes symmetry). A
         shard-group member (tp > 1) takes ``hostnames`` — its ``tp`` node
-        placement — instead of a single ``hostname``."""
+        placement — instead of a single ``hostname``. On a host with n > 1
+        devices the member lands on device ``replica_id % n``."""
+        params, device = self.params, None
+        if len(self._devices) > 1:
+            device = self._devices[self._next_replica % len(self._devices)]
+            if device not in self._device_params:
+                self._device_params[device] = jax.device_put(self.params,
+                                                             device)
+            params = self._device_params[device]
         rep = ServingReplica.build(
-            self.cfg, self.params, self._next_replica, hostname=hostname,
-            hostnames=hostnames, **{**self.replica_kw, **overrides})
+            self.cfg, params, self._next_replica, hostname=hostname,
+            hostnames=hostnames, device=device,
+            **{**self.replica_kw, **overrides})
         self.replicas[rep.replica_id] = rep
         self._next_replica += 1
         self.stats["replicas_added"] += 1

@@ -188,12 +188,22 @@ class ContinuousBatchingScheduler:
                  spec_k: Optional[int] = None, spec_draft=None,
                  host_pages: Optional[int] = None,
                  tenant_quotas: Optional[Dict[str, int]] = None,
-                 swap_crossover: Optional[int] = None):
+                 swap_crossover: Optional[int] = None, device: Any = None):
         if not supports_paged(cfg):
             raise NotImplementedError(
                 f"{cfg.name}: paged serving covers decoder-only non-MLA "
                 "archs; use repro.serving.engine for this one")
         self.cfg = cfg
+        # placement: with ``device`` set (one device per fleet replica, see
+        # ServingRouter) the params, draft params and page pools are
+        # committed to it, so every program of this scheduler runs there;
+        # None keeps JAX's default device
+        self.device = device
+        if device is not None:
+            params = jax.device_put(params, device)
+            if spec_draft is not None:
+                spec_draft = (spec_draft[0],
+                              jax.device_put(spec_draft[1], device))
         self.params = params
         self.page_size = page_size
         self.max_slots = max_slots
@@ -237,7 +247,9 @@ class ContinuousBatchingScheduler:
             prefill_fused = not self.exact_prefill
         self.prefill_fused = bool(prefill_fused) and not self.exact_prefill
         # bake the Pallas write+attend kernel pair into the fused programs
-        # (interpret-mode on CPU; flags.use_prefill_kernel at trace time)
+        # (interpret mode only on the CPU backend, see
+        # repro.kernels.resolve_interpret; flags.use_prefill_kernel at
+        # trace time)
         self.prefill_kernel = bool(prefill_kernel)
         # speculative decoding: each verify tick runs every decoding slot's
         # last token plus up to spec_k draft tokens as parallel rows of one
@@ -327,8 +339,9 @@ class ContinuousBatchingScheduler:
             self.host_tier.alloc.on_free = (
                 lambda p: self.index.invalidate_page(PC.as_host_page(p)))
 
-        self.cache = PC.init_paged_cache(cfg, num_pages, page_size, max_slots,
-                                         tp=tp)
+        self.cache = self._place(
+            lambda: PC.init_paged_cache(cfg, num_pages, page_size, max_slots,
+                                        tp=tp))
         # incremental draft-model cache: a parallel (unsharded) page pool at
         # the DRAFT's dims mirroring the target's page geometry 1:1 — the
         # draft reuses the target's block tables verbatim, so page alloc /
@@ -340,8 +353,9 @@ class ContinuousBatchingScheduler:
         # migration) only lower the accept rate — every draft token is
         # target-verified, so emitted tokens never depend on it.
         if spec_draft is not None:
-            self._draft_cache = PC.init_paged_cache(
-                spec_draft[0], num_pages, page_size, max_slots)
+            self._draft_cache = self._place(
+                lambda: PC.init_paged_cache(spec_draft[0], num_pages,
+                                            page_size, max_slots))
             self._draft_ready = [False] * max_slots
         self.alloc = PC.PageAllocator(num_pages)
         self.alloc.on_free = self.index.invalidate_page
@@ -448,6 +462,15 @@ class ContinuousBatchingScheduler:
         self._cow_fn = jax.jit(functools.partial(PC.copy_page, tp=tp),
                                donate_argnums=(0,))
         self._rid = 0
+
+    def _place(self, make):
+        """``make()``'s arrays, created on and committed to ``self.device``
+        (built there directly: a pool sized for one device's HBM must not
+        pass through another's)."""
+        if self.device is None:
+            return make()
+        with jax.default_device(self.device):
+            return jax.device_put(make(), self.device)
 
     # ------------------------------------------------------------ jit fns --
     @staticmethod
@@ -1640,6 +1663,8 @@ class ContinuousBatchingScheduler:
         state = None
         if self._has_ssm:
             state = PC.extract_ssm_slot(donor.cache, donor_slot)
+            if self.device is not None:
+                state = jax.device_put(state, self.device)
             self.cache = PC.merge_ssm_slot(
                 self.cache, PC.ssm_slot_view(self.cache, state), slot)
         row = np.full((self.n_pg,), PC.SINK_PAGE, np.int32)

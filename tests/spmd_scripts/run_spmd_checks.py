@@ -1,11 +1,12 @@
-"""Subprocess SPMD checks — run with 8 fake CPU devices.
+"""Subprocess SPMD checks — run with 8 fake CPU devices (or as many as
+``XLA_FLAGS`` asks for, when it is set).
 
 Executed by tests/test_spmd.py via subprocess so the main pytest process
 keeps its single-device view. Each check prints 'PASS <name>' on success.
 """
 import os
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import sys
 import tempfile
@@ -125,7 +126,6 @@ def check_compressed_psum():
     from repro.parallel.collectives import (compressed_psum,
                                             compression_error_bound,
                                             make_compressed_grad_sync)
-    from jax.experimental.shard_map import shard_map
     mesh = make_mesh_for(2, 2, 2)   # pod x data x model
     x = jax.random.normal(jax.random.PRNGKey(2), (2, 64, 64), jnp.float32)
     xs = jax.device_put(x, NamedSharding(mesh, P("pod")))
@@ -187,8 +187,8 @@ def check_gpipe_matches_sequential():
     for i in range(L):
         ref = body(jax.tree.map(lambda a: a[i], params), ref)
 
-    from repro.launch.mesh import _axis_kwargs
-    mesh = jax.make_mesh((4,), ("stage",), **_axis_kwargs(1))
+    mesh = jax.make_mesh((4,), ("stage",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     ps = jax.tree.map(
         lambda a: jax.device_put(a, NamedSharding(mesh, P("stage"))), params)
     with mesh:
@@ -282,6 +282,50 @@ def check_chunked_prefill_tp2():
     with mesh:
         assert serve(2, 4, shard_mesh=mesh) == want   # + real shard_map
     print("PASS chunked_prefill_tp2")
+
+
+def check_fleet_replica_placement():
+    """One device per replica: a 4-replica fleet (reduced config, fp32) on
+    a 4-device host puts each replica's params and page pools on its own
+    device, and emits byte-identical tokens to the same requests on one
+    replica."""
+    import dataclasses
+
+    from repro.serving.router import ServingRouter
+
+    assert jax.device_count() == 4, jax.devices()
+    cfg = dataclasses.replace(REDUCED["qwen3-32b"], dtype="float32")
+    params = M.init(cfg, jax.random.PRNGKey(0))
+    rng = np.random.RandomState(4)
+    trace = [(rng.randint(0, cfg.vocab_size, size=p).astype(np.int32), g)
+             for p, g in ((13, 5), (21, 4), (6, 6), (30, 3), (17, 5),
+                          (9, 4), (25, 6), (11, 3))]
+
+    def serve(k):
+        router = ServingRouter(cfg, params, replicas=k, max_slots=2,
+                               page_size=8, max_seq_len=48,
+                               route_policy="round-robin")
+        reqs = [router.submit(p, g, arrival_step=i // 2)
+                for i, (p, g) in enumerate(trace)]
+        router.run()
+        return router, [list(r.out_tokens) for r in reqs]
+
+    fleet, got = serve(4)
+    placed = []
+    for rep in fleet.replicas.values():
+        for tree in (rep.sched.params, rep.sched.cache):
+            devs = {d for leaf in jax.tree_util.tree_leaves(tree)
+                    for d in leaf.devices()}
+            assert len(devs) == 1, (rep.replica_id, devs)
+        assert rep.devices() == [str(d) for d in devs], rep.devices()
+        placed.append(devs.pop())
+    assert sorted(placed, key=str) == sorted(jax.devices(), key=str), placed
+    assert fleet.fleet_stats()["tokens_out"] == sum(g for _, g in trace)
+    assert all(r.sched.stats["tokens_out"] > 0      # all four served
+               for r in fleet.replicas.values())
+    _, want = serve(1)
+    assert got == want, (got, want)
+    print("PASS fleet_replica_placement")
 
 
 if __name__ == "__main__":

@@ -182,6 +182,5 @@ def test_profiler_summary_is_sane():
         assert s["wall_s"] > 0.0, kind
         assert s["modeled_flops"] > 0.0, kind
         assert s["modeled_bytes"] > 0.0, kind
-        # CPU interpreter walls are far off the roofline but the fraction
-        # must be a positive finite number
-        assert 0.0 < s["roofline_frac"] < 1.0, (kind, s)
+        # the CPU backend has no published peaks: no fraction is made up
+        assert s["roofline_frac"] is None, (kind, s)

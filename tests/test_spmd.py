@@ -1,5 +1,6 @@
 """Real-SPMD integration tests: run checks in a subprocess with 8 fake CPU
-devices (keeps the main pytest process single-device)."""
+devices, or as many as a check asks for (keeps the main pytest process
+single-device)."""
 import pathlib
 import subprocess
 import sys
@@ -10,9 +11,10 @@ SCRIPT = pathlib.Path(__file__).parent / "spmd_scripts" / "run_spmd_checks.py"
 SRC = str(pathlib.Path(__file__).parent.parent / "src")
 
 
-def run_check(name: str, timeout: int = 900) -> str:
+def run_check(name: str, timeout: int = 900, devices: int = 8) -> str:
     env = {"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin:/usr/local/bin",
-           "JAX_PLATFORMS": "cpu", "HOME": "/tmp"}
+           "JAX_PLATFORMS": "cpu", "HOME": "/tmp",
+           "XLA_FLAGS": f"--xla_force_host_platform_device_count={devices}"}
     proc = subprocess.run([sys.executable, str(SCRIPT), name],
                           capture_output=True, text=True, timeout=timeout,
                           env=env)
@@ -54,3 +56,8 @@ def test_shard_group_paged_decode_shard_map():
 @pytest.mark.slow
 def test_chunked_prefill_composes_with_tp2():
     run_check("chunked_prefill_tp2")
+
+
+@pytest.mark.slow
+def test_fleet_replica_placement():
+    run_check("fleet_replica_placement", devices=4)
