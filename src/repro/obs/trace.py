@@ -1,18 +1,23 @@
-"""Request-lifecycle tracing on the sim tick clock.
+"""Request-lifecycle tracing, on the sim tick clock or on a wall clock.
 
 A ``Tracer`` collects, per request, the spans of its lifecycle —
 
-    queued -> [prefill | prefill_chunk[k]...] -> (parked -> page_migration
-    ->) decode -> finish
+    queued -> prefill [prefill_chunk[k]...] -> held -> (parked ->
+    page_migration ->) decode -> finish
 
 — plus fleet-level instant events (``routed``, ``admitted``,
 ``page_migration``, ``reroute``, ``failover``, ``autoscale``), each
 annotated with the replica / shard group it ran on and page / prefix /
-migration detail. Time is the simulation tick clock: the scheduler stamps
-its own ``step_idx`` when standalone, and the fabric router stamps the
-*fleet* clock for every replica it drives (replica clocks drift through
-idle-gap skipping, so per-replica ticks would not line up on one
-timeline).
+migration detail.
+
+By default time is the simulation tick clock: the scheduler stamps its own
+``step_idx`` when standalone, and the fabric router stamps the *fleet*
+clock for every replica it drives (replica clocks drift through idle-gap
+skipping, so per-replica ticks would not line up on one timeline).
+``Tracer(clock=time.perf_counter)`` stamps every hook from that clock
+instead, in seconds; a scheduler driving such a tracer also records the
+phases of each ``step()`` (``step``, ``admit``, ``dispatch``, ``wait``) on
+the scheduler lane, ``rid == LANE``.
 
 Tracing is read-only by contract: hooks observe scheduler state and never
 touch it, so a traced run emits byte-identical tokens to an untraced one
@@ -23,7 +28,7 @@ Exports:
 * ``write_chrome`` — Chrome trace-event JSON (open in Perfetto or
   ``chrome://tracing``): spans are ``"X"`` complete events with
   ``pid``/``process_name`` per replica and ``tid`` per request, one tick
-  rendered as 1 ms;
+  rendered as 1 ms, or one second as 1 s on a wall clock;
 * ``write_jsonl`` / ``from_jsonl`` — a lossless JSON-lines round trip
   with the same fail-loud contract as ``repro.core.events.EventLog``
   (malformed input raises ``ValueError`` naming the 1-based line);
@@ -35,15 +40,19 @@ from __future__ import annotations
 
 import dataclasses
 import json
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from repro.core.events import EventLog
 
-__all__ = ["Span", "Instant", "Tracer", "TICK_US"]
+__all__ = ["Span", "Instant", "Tracer", "TICK_US", "LANE"]
 
 # one sim tick rendered as 1000 trace-event microseconds (= 1 ms), so a
 # few-hundred-tick serve run spans a readable fraction of a second
 TICK_US = 1000.0
+# a wall-clock second in trace-event microseconds
+SECOND_US = 1e6
+# the ``rid`` of spans that belong to a scheduler rather than a request
+LANE = -1
 
 
 @dataclasses.dataclass
@@ -76,18 +85,21 @@ class Instant:
 
 
 class Tracer:
-    """Span/instant collector on a settable tick clock.
+    """Span/instant collector on a settable tick clock or a wall clock.
 
-    The clock (``t``) is pushed by whoever owns the timeline —
-    ``set_tick`` from the scheduler's or router's step loop — so hook
-    sites just call ``begin``/``end``/``span``/``instant`` without
-    plumbing a time argument. ``begin`` on an already-open ``(rid, name)``
-    and ``end`` on a never-opened one are silent no-ops: a request may
-    predate the tracer's attachment, and the fleet path opens ``queued``
-    at the router while the replica path would open it again.
+    Without ``clock`` the time (``t``) is pushed by whoever owns the
+    timeline — ``set_tick`` from the scheduler's or router's step loop —
+    so hook sites just call ``begin``/``end``/``span``/``instant`` without
+    plumbing a time argument. With ``clock`` (any callable returning
+    seconds, e.g. ``time.perf_counter``) every hook without an explicit
+    ``t`` reads it. ``begin`` on an already-open ``(rid, name)`` and
+    ``end`` on a never-opened one are silent no-ops: a request may predate
+    the tracer's attachment, and the fleet path opens ``queued`` at the
+    router while the replica path would open it again.
     """
 
-    def __init__(self) -> None:
+    def __init__(self, clock: Optional[Callable[[], float]] = None) -> None:
+        self.clock = clock
         self.t = 0.0
         self.spans: List[Span] = []
         self.instants: List[Instant] = []
@@ -98,23 +110,33 @@ class Tracer:
     def set_tick(self, t: float) -> None:
         self.t = float(t)
 
+    def now(self) -> float:
+        """The current time: the wall clock's reading, else the tick."""
+        return self.t if self.clock is None else float(self.clock())
+
     # ------------------------------------------------------------- record --
     def begin(self, name: str, rid: int, *, t: Optional[float] = None,
               replica: Optional[int] = None, **attrs: Any) -> None:
         key = (rid, name)
         if key in self._open:
             return                        # first opener wins (fleet submit)
-        self._open[key] = Span(name, rid, float(self.t if t is None else t),
-                               -1.0, replica, dict(attrs))
+        self._open[key] = Span(name, rid, self.now() if t is None
+                               else float(t), -1.0, replica, dict(attrs))
 
     def end(self, name: str, rid: int, *, t: Optional[float] = None,
             **attrs: Any) -> None:
         span = self._open.pop((rid, name), None)
         if span is None:
             return                        # unmatched end: tolerated no-op
-        span.t1 = float(self.t if t is None else t)
+        span.t1 = self.now() if t is None else float(t)
         span.attrs.update(attrs)
         self.spans.append(span)
+
+    def annotate(self, name: str, rid: int, **attrs: Any) -> None:
+        """Set attributes of an open span (no-op when it is not open)."""
+        span = self._open.get((rid, name))
+        if span is not None:
+            span.attrs.update(attrs)
 
     def span(self, name: str, rid: int, t0: float, t1: float, *,
              replica: Optional[int] = None, **attrs: Any) -> None:
@@ -126,8 +148,9 @@ class Tracer:
     def instant(self, name: str, *, rid: Optional[int] = None,
                 t: Optional[float] = None, replica: Optional[int] = None,
                 **attrs: Any) -> None:
-        self.instants.append(Instant(name, float(self.t if t is None else t),
-                                     rid, replica, dict(attrs)))
+        self.instants.append(Instant(name, self.now() if t is None
+                                     else float(t), rid, replica,
+                                     dict(attrs)))
 
     def next_index(self, rid: int, name: str) -> int:
         """Per-(request, name) running index — chunk numbering."""
@@ -138,14 +161,15 @@ class Tracer:
     def set_process_name(self, pid: int, label: str) -> None:
         self.process_names[int(pid)] = str(label)
 
-    def finish_open(self) -> int:
-        """Close every still-open span at the current tick (export time on
-        a run that was interrupted or is mid-flight), marking it
+    def finish_open(self, t: Optional[float] = None) -> int:
+        """Close every still-open span at ``t``, by default now (export
+        time on a run that was interrupted or is mid-flight), marking it
         ``open=True``; returns how many were flushed."""
+        t = self.now() if t is None else float(t)
         n = 0
         for key in sorted(self._open, key=lambda k: (str(k[1]), k[0])):
             span = self._open.pop(key)
-            span.t1 = max(self.t, span.t0)
+            span.t1 = max(t, span.t0)
             span.attrs["open"] = True
             self.spans.append(span)
             n += 1
@@ -156,10 +180,13 @@ class Tracer:
         """Chrome trace-event JSON object (Perfetto / chrome://tracing).
 
         ``pid`` = replica id (+1; pid 0 is the fleet/router plane so
-        replica 0 keeps its own lane), ``tid`` = request id, ``ts``/
-        ``dur`` in microseconds at ``TICK_US`` per tick. Span/instant
-        attrs (including ``replica``) travel in ``args``.
+        replica 0 keeps its own lane), ``tid`` = request id (``LANE`` for
+        a scheduler's step phases), ``ts``/``dur`` in microseconds at
+        ``TICK_US`` per tick, or ``SECOND_US`` per second on a wall clock
+        (``otherData.clock`` says which). Span/instant attrs (including
+        ``replica``) travel in ``args``.
         """
+        unit = TICK_US if self.clock is None else SECOND_US
         events: List[Dict[str, Any]] = []
         events.append({"ph": "M", "name": "process_name", "pid": 0,
                        "args": {"name": "fleet"}})
@@ -174,8 +201,8 @@ class Tracer:
             events.append({
                 "ph": "X", "name": s.name, "cat": "request",
                 "pid": _pid(s.replica), "tid": int(s.rid),
-                "ts": s.t0 * TICK_US,
-                "dur": max(s.t1 - s.t0, 0.0) * TICK_US,
+                "ts": s.t0 * unit,
+                "dur": max(s.t1 - s.t0, 0.0) * unit,
                 "args": {"rid": s.rid, "replica": s.replica, **s.attrs},
             })
         for i in self.instants:
@@ -183,12 +210,14 @@ class Tracer:
                 "ph": "i", "name": i.name, "cat": "fleet",
                 "pid": _pid(i.replica),
                 "tid": int(i.rid) if i.rid is not None else 0,
-                "ts": i.t * TICK_US,
+                "ts": i.t * unit,
                 "s": "g" if i.rid is None else "t",
                 "args": {"rid": i.rid, "replica": i.replica, **i.attrs},
             })
+        clock = (f"sim tick = {TICK_US} us" if self.clock is None
+                 else f"wall clock, 1 s = {SECOND_US:g} us")
         return {"traceEvents": events, "displayTimeUnit": "ms",
-                "otherData": {"clock": f"sim tick = {TICK_US} us"}}
+                "otherData": {"clock": clock}}
 
     def write_chrome(self, path: str) -> int:
         """Write Chrome trace JSON; returns the number of trace events."""

@@ -222,7 +222,11 @@ class ServingRouter:
     def set_tracer(self, tracer) -> None:
         """Attach one lifecycle tracer fleet-wide. Every replica's hooks
         stamp the *fleet* clock (replica clocks drift through idle-gap
-        skipping), so all spans share a single timeline."""
+        skipping), so all spans share a single timeline. A wall-clock
+        tracer belongs to one scheduler, whose own steps it times."""
+        if tracer.clock is not None:
+            raise ValueError("the fleet traces on its tick clock; attach a "
+                             "wall-clock Tracer to one scheduler")
         self.tracer = tracer
         for rep in self.replicas.values():
             self._wire_obs(rep)
@@ -338,7 +342,7 @@ class ServingRouter:
         tr = self.tracer
         if tr is not None:
             # the lost stream's open span (whichever state it died in)
-            for name in ("decode", "parked", "queued"):
+            for name in ("decode", "parked", "queued", "prefill"):
                 tr.end(name, req.rid, t=self.step_idx, lost=True)
         orig = self._parents.pop(req.rid, req)   # chain continuations
         orig.replica = None

@@ -79,6 +79,7 @@ per-shard page-budget math.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import functools
 from typing import Any, Deque, Dict, List, Optional, Sequence
@@ -92,6 +93,7 @@ from repro.models import flags as model_flags
 from repro.models import model as M
 from repro.models.transformer import lm_forward
 from repro.obs.metrics import MetricsRegistry, StatsView, TICK_BUCKETS
+from repro.obs.trace import LANE
 from repro.parallel.context import ShardGroup
 from repro.serving import paged_cache as PC
 from repro.serving.request import Request, make_request
@@ -103,6 +105,12 @@ __all__ = ["ContinuousBatchingScheduler", "DEFAULT_BUCKETS", "Request",
            "supports_paged"]
 
 _EMPTY_DRAFT = np.zeros((0,), np.int32)
+
+
+def _upload(args) -> list:
+    """A program call's arguments with its host arrays put on the device."""
+    return [jnp.asarray(a) if isinstance(a, (np.ndarray, np.generic)) else a
+            for a in args]
 
 
 def spec_accept(drafts, targets) -> int:
@@ -396,6 +404,10 @@ class ContinuousBatchingScheduler:
         self.replica_id: Optional[int] = None   # set by ServingReplica
         self.tracer = None                      # set via set_tracer
         self._trace_own_clock = True            # router flips: fleet clock
+        self._wall = None                       # the tracer, on a wall clock
+        self._phases = False                    # step phases recorded
+        self._profiled = False                  # enable_profiling was called
+        self._held: List[int] = []              # rids with ``held`` open
         self.profiler = None                    # set via enable_profiling
         self.registry = MetricsRegistry()
         _gauges = ("peak_pages", "spec_accept_rate", "host_pages_used",
@@ -800,20 +812,17 @@ class ContinuousBatchingScheduler:
             toks[:L] = ctx
             self._draft_cache = self._timed(
                 "spec_draft", self._draft_prefill_fn(b), dparams,
-                self._draft_cache, jnp.asarray(toks),
-                jnp.asarray(L, jnp.int32),
-                jnp.asarray(self.block_table[slot]), tokens=L, ctx_tokens=L)
+                self._draft_cache, toks, np.int32(L),
+                self.block_table[slot], tokens=L, ctx_tokens=L)
             self._draft_ready[slot] = True
         live = np.zeros((S,), bool)
         live[elig] = True
         ds, self._draft_cache = self._timed(
             "spec_draft", self._draft_advance_fn(), dparams,
-            self._draft_cache, jnp.asarray(self.last_tokens[:, 0]),
-            jnp.asarray(self.seq_lens), jnp.asarray(live),
-            jnp.asarray(self.block_table),
-            tokens=(k + 1) * len(elig),
+            self._draft_cache, self.last_tokens[:, 0], self.seq_lens, live,
+            self.block_table, tokens=(k + 1) * len(elig),
             ctx_tokens=int(np.sum(self.seq_lens[elig])))
-        ds = np.asarray(ds)
+        ds = self._fetch(ds)
         return {slot: ds[slot].astype(np.int32) for slot in elig}
 
     # -------------------------------------------------------- draft sources --
@@ -856,28 +865,73 @@ class ContinuousBatchingScheduler:
 
         ``own_clock=False`` means somebody else — the fabric router —
         drives ``tracer.t`` on the fleet clock, so hooks stamp that;
-        otherwise they stamp this scheduler's own ``step_idx``.
+        otherwise they stamp this scheduler's own ``step_idx``. A tracer
+        with a wall ``clock`` is stamped from that clock, and then also
+        gets the phases of every ``step()`` (``_phase``).
         """
         self.tracer = tracer
         self._trace_own_clock = own_clock
+        self._wall = (tracer if tracer is not None
+                      and tracer.clock is not None else None)
+        self._phases = self._wall is not None or self._profiled
+        self._held = []
 
     def _tnow(self) -> float:
+        if self._wall is not None:
+            return self._wall.now()
         return (float(self.step_idx) if self._trace_own_clock
                 else self.tracer.t)
+
+    @contextlib.contextmanager
+    def _phase(self, name: str, **attrs):
+        """One phase of a step, entered as ``serving.<name>`` on the JAX
+        profiler's host timeline, so that a profiler trace puts device
+        gaps down to it, and on the wall-clock tracer's scheduler lane when
+        there is one. Only called with phases on (a wall-clock tracer or a
+        profiling hook); it reads the clock and nothing of the device."""
+        tr = self._wall
+        t0 = tr.now() if tr is not None else None
+        with jax.profiler.TraceAnnotation("serving." + name):
+            yield
+        if tr is not None:
+            tr.span(name, LANE, t0, tr.now(), replica=self.replica_id,
+                    **attrs)
+
+    def _fetch(self, x) -> np.ndarray:
+        """A device result on the host: the ``wait`` phase, where the host
+        blocks on the device."""
+        if not self._phases:
+            return np.asarray(x)
+        with self._phase("wait"):
+            return np.asarray(x)
 
     def enable_profiling(self, profiler=None):
         """Opt-in kernel dispatch timing (``repro.obs.profile``): every
         prefill/suffix/decode dispatch is wall-timed after
         ``block_until_ready`` with its token/context detail. Read-only —
-        profiled runs emit byte-identical tokens."""
+        profiled runs emit byte-identical tokens. A profiled scheduler also
+        enters the phases of every ``step()`` (``step``, ``admit``,
+        ``dispatch``, ``wait``) as ``serving.<phase>`` on the JAX
+        profiler's host timeline, tracer or not."""
         if profiler is None:
             from repro.obs.profile import KernelProfiler
             profiler = KernelProfiler(self.cfg, tp=self.tp)
         self.profiler = profiler
+        self._profiled = self._phases = True
         return profiler
 
     def _timed(self, kind: str, fn, *args, tokens: int = 0,
                ctx_tokens: int = 0, **kw):
+        """One program call. Host arrays among ``args`` are uploaded here,
+        so that the ``dispatch`` phase covers the upload and the call."""
+        if not self._phases:
+            return self._call(kind, fn, args, tokens, ctx_tokens, kw)
+        with self._phase("dispatch", kind=kind, tokens=tokens,
+                         k=kw.get("k", 1)):
+            return self._call(kind, fn, args, tokens, ctx_tokens, kw)
+
+    def _call(self, kind: str, fn, args, tokens: int, ctx_tokens: int, kw):
+        args = _upload(args)
         if self.profiler is None:
             return fn(*args, **kw)
         return self.profiler.timed(kind, fn, *args, tokens=tokens,
@@ -916,7 +970,8 @@ class ContinuousBatchingScheduler:
         if self.tracer is not None:
             # no-op when the fabric router already opened this span at its
             # own submit (first opener wins — fleet clock beats replica's)
-            self.tracer.begin("queued", req.rid, t=req.arrival_step,
+            self.tracer.begin("queued", req.rid, t=req.arrival_step
+                              if self._wall is None else self._tnow(),
                               replica=self.replica_id)
         return req
 
@@ -945,7 +1000,7 @@ class ContinuousBatchingScheduler:
             for req in due:
                 free = self._free_slots()   # _admit may have finished slots
                 if not free:
-                    self.stats["admit_blocked"] += 1
+                    self._blocked(req, "slots")
                     return
                 hit = self._prefix_lookup(req)
                 reserve, demand = self._admission_demand(req, hit)
@@ -959,7 +1014,7 @@ class ContinuousBatchingScheduler:
                     # chain from being evicted out from under us)
                     if not self._reclaim(demand - headroom,
                                          protect=self._hit_pages(hit)):
-                        self.stats["admit_blocked"] += 1
+                        self._blocked(req, "pages")
                         return              # head of the class blocks
                     # eviction may have remapped or invalidated entries
                     hit = self._prefix_lookup(req)
@@ -967,7 +1022,7 @@ class ContinuousBatchingScheduler:
                     if demand > (self.alloc.num_free
                                  - (self.reserved_pages
                                     - self.pages_in_use)):
-                        self.stats["admit_blocked"] += 1
+                        self._blocked(req, "pages")
                         return
                 mat = self._materialize_hit(req, hit)
                 if mat is None and hit is not None:
@@ -977,19 +1032,32 @@ class ContinuousBatchingScheduler:
                     if demand > (self.alloc.num_free
                                  - (self.reserved_pages
                                     - self.pages_in_use)):
-                        self.stats["admit_blocked"] += 1
+                        self._blocked(req, "pages")
                         return
                 hit = mat
                 self.waiting.remove(req)
+                t = None                    # the admission decision
+                if self.tracer is not None:
+                    t = self._tnow()
+                    self.tracer.end("queued", req.rid, t=t)
+                    self.tracer.begin("prefill", req.rid, t=t,
+                                      replica=self.replica_id)
                 if self.tenant_quotas is not None:
                     self._tenant_reserved[req.tenant] = (
                         self._tenant_reserved.get(req.tenant, 0) + reserve)
                 if self.prefill_budget is not None:
-                    self._admit_chunked(req, free[0], reserve, hit)
+                    self._admit_chunked(req, free[0], reserve, hit, t)
                 else:
-                    self._admit(req, free[0], reserve, hit)
+                    self._admit(req, free[0], reserve, hit, t)
                 progress = True
                 break                       # re-scan with fresh due window
+
+    def _blocked(self, req: Request, reason: str) -> None:
+        """An admission attempt blocked on ``slots`` or ``pages``; the
+        request's ``queued`` span keeps the reason of its last one."""
+        self.stats["admit_blocked"] += 1
+        if self.tracer is not None:
+            self.tracer.annotate("queued", req.rid, blocked=reason)
 
     def _admission_demand(self, req: Request, hit):
         """``(reserve, demand)`` pages for admitting ``req`` against ``hit``.
@@ -1026,6 +1094,8 @@ class ContinuousBatchingScheduler:
         if self._tenant_reserved.get(req.tenant, 0) + reserve <= quota:
             return False
         self.stats["quota_blocked"] += 1
+        if self.tracer is not None:
+            self.tracer.annotate("queued", req.rid, blocked="quota")
         return True
 
     @staticmethod
@@ -1316,13 +1386,23 @@ class ContinuousBatchingScheduler:
                 return b
         return self.max_seq_len
 
-    def _admit(self, req: Request, slot: int, reserve: int,
-               hit=None) -> None:
+    def _admit(self, req: Request, slot: int, reserve: int, hit,
+               t_admit: Optional[float]) -> None:
+        """Monolithic admission: prefill the prompt (or the suffix past a
+        prefix hit) and hold the first token; ``t_admit`` is the tracer's
+        time of the admission decision."""
         plen = req.plen
         if hit is None:
             first, pages, shared, row = self._admit_full(req, slot)
         else:
             first, pages, shared, row = self._admit_shared(req, slot, hit)
+        tr = self.tracer
+        if tr is not None:                  # the first token is on the host
+            now = self._tnow()
+            tr.end("prefill", req.rid, t=now, tokens=plen,
+                   cached_tokens=req.cached_tokens, pages=len(pages),
+                   shared_pages=shared)
+            self._hold(req, now)
         self.reserved_pages += reserve
         self.block_table[slot] = row
         self.seq_lens[slot] = plen
@@ -1339,18 +1419,11 @@ class ContinuousBatchingScheduler:
         self.stats["tokens_out"] += 1
         self.h_queue_wait.observe(req.admit_step - req.arrival_step)
         self.h_ttft.observe(self.step_idx - req.arrival_step)
-        tr = self.tracer
         if tr is not None:
-            now = self._tnow()
-            tr.end("queued", req.rid, t=now)
-            tr.instant("admitted", rid=req.rid, t=now,
+            tr.instant("admitted", rid=req.rid, t=t_admit,
                        replica=self.replica_id, slot=slot, pages=len(pages),
                        shared_pages=shared, cached_tokens=req.cached_tokens,
                        prefix_hit=hit is not None)
-            tr.span("prefill", req.rid, now, now + 1,
-                    replica=self.replica_id, tokens=plen,
-                    cached_tokens=req.cached_tokens, pages=len(pages),
-                    shared_pages=shared)
         if req.done:                        # max_new_tokens == 1
             self._finish(slot)
             self._admit_done.append(req)
@@ -1380,16 +1453,14 @@ class ContinuousBatchingScheduler:
             self.stats["prefill_dispatches"] += 1
             first, self.cache = self._timed(
                 "prefill", self._chunk_fn(n), self.params, self.cache,
-                jnp.asarray(toks), jnp.asarray(0, jnp.int32),
-                jnp.asarray(plen, jnp.int32), jnp.asarray(row), tokens=plen)
+                toks, np.int32(0), np.int32(plen), row, tokens=plen)
             state = None
         else:
             tokens = np.zeros((1, n), np.int32)
             tokens[0, :plen] = req.prompt
             self.stats["prefill_dispatches"] += 2    # prefill + insert
             first, pre = self._timed("prefill", self._prefill_fn(n),
-                                     self.params, jnp.asarray(tokens),
-                                     jnp.asarray(plen, jnp.int32),
+                                     self.params, tokens, np.int32(plen),
                                      tokens=plen)
             self.cache = self._insert_fn(n)(self.cache, pre,
                                             jnp.asarray(row),
@@ -1399,7 +1470,7 @@ class ContinuousBatchingScheduler:
         if self.prefix_cache:
             self.index.insert(req.prompt, pages, state=state)
             self.stats["prefix_misses"] += 1
-        return int(first), pages, 0, row
+        return int(self._fetch(first)), pages, 0, row
 
     def _admit_shared(self, req: Request, slot: int, hit):
         """Prefix-cache hit: share the matched full pages, COW-fork the
@@ -1424,9 +1495,8 @@ class ContinuousBatchingScheduler:
         if self.exact_prefill:
             first, self.cache = self._timed(
                 "prefill_seq", self._seq_suffix_fn(s),
-                self.params, self.cache, hit.state, jnp.asarray(suffix),
-                jnp.asarray(L, jnp.int32), jnp.asarray(row),
-                jnp.asarray(slot, jnp.int32), tokens=s, ctx_tokens=L)
+                self.params, self.cache, hit.state, suffix, np.int32(L),
+                row, np.int32(slot), tokens=s, ctx_tokens=L)
         else:
             n = self._bucket(s)
             toks = np.zeros((n,), np.int32)
@@ -1434,10 +1504,8 @@ class ContinuousBatchingScheduler:
             fn = (self._chunk_fn(n) if self.prefill_fused
                   else self._suffix_fn(n))
             first, self.cache = self._timed(
-                "prefill_suffix", fn,
-                self.params, self.cache, jnp.asarray(toks),
-                jnp.asarray(L, jnp.int32), jnp.asarray(s, jnp.int32),
-                jnp.asarray(row), tokens=s, ctx_tokens=L)
+                "prefill_suffix", fn, self.params, self.cache, toks,
+                np.int32(L), np.int32(s), row, tokens=s, ctx_tokens=L)
         if not self._has_ssm:
             # extend the index with this prompt's own (longer) chain; hybrid
             # entries need a state snapshot, which only full prefills have
@@ -1445,11 +1513,17 @@ class ContinuousBatchingScheduler:
         req.cached_tokens = L
         self.stats["prefix_hits"] += 1
         self.stats["cached_tokens"] += L
-        return int(first), pages, len(shared), row
+        return int(self._fetch(first)), pages, len(shared), row
+
+    def _hold(self, req: Request, t: float) -> None:
+        """Open ``held``: the first token is on the host at ``t`` and is
+        returned when this ``step()`` ends, which closes the span."""
+        self.tracer.begin("held", req.rid, t=t, replica=self.replica_id)
+        self._held.append(req.rid)
 
     # ------------------------------------------------------ chunked prefill --
-    def _admit_chunked(self, req: Request, slot: int, reserve: int,
-                       hit=None) -> None:
+    def _admit_chunked(self, req: Request, slot: int, reserve: int, hit,
+                       t_admit: Optional[float]) -> None:
         """Allocate the prompt's pages and enter PREFILLING — no model call.
 
         The prompt lands chunk by chunk in ``_advance_prefills``; until the
@@ -1499,9 +1573,7 @@ class ContinuousBatchingScheduler:
         self.h_queue_wait.observe(req.admit_step - req.arrival_step)
         tr = self.tracer
         if tr is not None:
-            now = self._tnow()
-            tr.end("queued", req.rid, t=now)
-            tr.instant("admitted", rid=req.rid, t=now,
+            tr.instant("admitted", rid=req.rid, t=t_admit,
                        replica=self.replica_id, slot=slot, chunked=True,
                        pages=len(pages), shared_pages=shared,
                        cached_tokens=start, prefix_hit=hit is not None)
@@ -1536,6 +1608,8 @@ class ContinuousBatchingScheduler:
         chunk). The last chunk's logits yield the first output token,
         exactly where monolithic prefill reads them.
         """
+        tr = self.tracer
+        t_issue = self._tnow() if tr is not None else None
         row = self.block_table[slot]
         chunk = np.asarray(req.prompt[pos:pos + c], np.int32)
         if self.prefill_fused:
@@ -1547,18 +1621,16 @@ class ContinuousBatchingScheduler:
             # monolithic path established; continuations are suffixes
             tok, self.cache = self._timed(
                 "prefill" if pos == 0 else "prefill_suffix",
-                self._chunk_fn(n),
-                self.params, self.cache, jnp.asarray(toks),
-                jnp.asarray(pos, jnp.int32), jnp.asarray(c, jnp.int32),
-                jnp.asarray(row), tokens=c, ctx_tokens=pos)
+                self._chunk_fn(n), self.params, self.cache, toks,
+                np.int32(pos), np.int32(c), row, tokens=c, ctx_tokens=pos)
         elif pos == 0:
             n = self._bucket(c)
             tokens = np.zeros((1, n), np.int32)
             tokens[0, :c] = chunk
             self.stats["prefill_dispatches"] += 2    # prefill + insert
             tok, pre = self._timed("prefill", self._prefill_fn(n),
-                                   self.params, jnp.asarray(tokens),
-                                   jnp.asarray(c, jnp.int32), tokens=c)
+                                   self.params, tokens, np.int32(c),
+                                   tokens=c)
             self.cache = self._insert_fn(n)(self.cache, pre,
                                             jnp.asarray(row),
                                             jnp.asarray(slot, jnp.int32),
@@ -1570,23 +1642,19 @@ class ContinuousBatchingScheduler:
             self.stats["prefill_dispatches"] += 1
             tok, self.cache = self._timed(
                 "prefill_seq", self._seq_suffix_fn(c),
-                self.params, self.cache, state, jnp.asarray(chunk),
-                jnp.asarray(pos, jnp.int32), jnp.asarray(row),
-                jnp.asarray(slot, jnp.int32), tokens=c, ctx_tokens=pos)
+                self.params, self.cache, state, chunk, np.int32(pos), row,
+                np.int32(slot), tokens=c, ctx_tokens=pos)
         else:
             n = self._bucket(c)
             toks = np.zeros((n,), np.int32)
             toks[:c] = chunk
             self.stats["prefill_dispatches"] += 1
             tok, self.cache = self._timed(
-                "prefill_suffix", self._suffix_fn(n),
-                self.params, self.cache, jnp.asarray(toks),
-                jnp.asarray(pos, jnp.int32), jnp.asarray(c, jnp.int32),
-                jnp.asarray(row), tokens=c, ctx_tokens=pos)
-        tr = self.tracer
+                "prefill_suffix", self._suffix_fn(n), self.params,
+                self.cache, toks, np.int32(pos), np.int32(c), row,
+                tokens=c, ctx_tokens=pos)
         if tr is not None:
-            now = self._tnow()
-            tr.span("prefill_chunk", req.rid, now, now + 1,
+            tr.span("prefill_chunk", req.rid, t_issue, self._tnow(),
                     replica=self.replica_id,
                     chunk=tr.next_index(req.rid, "prefill_chunk"),
                     pos=pos, tokens=c)
@@ -1607,7 +1675,14 @@ class ContinuousBatchingScheduler:
         self.slot_resume_state[slot] = None
         req.prefill_pos = None
         self.seq_lens[slot] = req.plen
-        first = int(tok)
+        first = int(self._fetch(tok))
+        if tr is not None:                  # the first token is on the host
+            now = self._tnow()
+            tr.end("prefill", req.rid, t=now, tokens=req.plen,
+                   cached_tokens=req.cached_tokens,
+                   pages=len(self.slot_pages[slot]),
+                   shared_pages=self.slot_shared[slot])
+            self._hold(req, now)
         self.last_tokens[slot, 0] = first
         req.out_tokens.append(first)
         self.stats["prefills"] += 1
@@ -1866,24 +1941,25 @@ class ContinuousBatchingScheduler:
             lens0[slot] = self.seq_lens[slot]
             bt[slot] = self.block_table[slot]
             live[slot] = cap + 1
+        tr = self.tracer
+        t_verify = self._tnow() if tr is not None else None
         if self._has_ssm:
             outs, js, self.cache = self._timed(
                 "verify", self._verify_seq_fn(n), self.params, self.cache,
-                jnp.asarray(toks), jnp.asarray(lens0), jnp.asarray(bt),
-                jnp.asarray(live), tokens=n * len(decoding), ctx_tokens=ctx)
-            outs = np.asarray(outs).T                      # (S, n)
-            js = np.asarray(js)
+                toks, lens0, bt, live, tokens=n * len(decoding),
+                ctx_tokens=ctx)
+            outs = self._fetch(outs).T                     # (S, n)
+            js = self._fetch(js)
         else:
             outs, self.cache = self._timed(
                 "verify", self._verify_fn(n), self.params, self.cache,
-                jnp.asarray(toks), jnp.asarray(lens0), jnp.asarray(bt),
-                jnp.asarray(live), tokens=n * len(decoding), ctx_tokens=ctx)
-            outs = np.asarray(outs)                        # (S, n)
+                toks, lens0, bt, live, tokens=n * len(decoding),
+                ctx_tokens=ctx)
+            outs = self._fetch(outs)                       # (S, n)
             js = None
         self.stats["decode_steps"] += 1
         self.stats["spec_ticks"] += 1
         self.step_idx += 1                  # before _finish (cf. step())
-        tr = self.tracer
         for slot in decoding:
             req = self.slot_req[slot]
             cap = caps[slot]
@@ -1900,7 +1976,7 @@ class ContinuousBatchingScheduler:
             self.last_tokens[slot, 0] = emitted[-1]
             if tr is not None and cap:
                 now = self._tnow()
-                tr.span("spec_verify", req.rid, now - 1, now,
+                tr.span("spec_verify", req.rid, t_verify, now,
                         replica=self.replica_id, drafted=cap, accepted=j)
             if req.done:
                 req.speculating = False
@@ -2040,9 +2116,41 @@ class ContinuousBatchingScheduler:
         no waiting arrival becomes due — so the schedule (and every token)
         is identical to single-stepping. Returns the requests that finished.
         A tick with no active slots (arrival gap) only advances the clock.
+        With a tracer, the first tokens this step produced are ``held``
+        until it returns; a wall-clock tracer also gets the ``step`` span,
+        and with phases on the step is ``serving.step`` on the profiler.
         """
+        tr = self.tracer
+        if not self._phases:
+            if tr is None:
+                return self._step(max_fuse)
+            done = self._step(max_fuse)
+            t = self._tnow()
+        else:
+            wall, k0 = self._wall, self.step_idx
+            t0 = wall.now() if wall is not None else None
+            with jax.profiler.TraceAnnotation("serving.step"):
+                done = self._step(max_fuse)
+            if wall is None:
+                if tr is None:
+                    return done
+                t = self._tnow()
+            else:
+                t = wall.now()
+                wall.span("step", LANE, t0, t, replica=self.replica_id,
+                          ticks=self.step_idx - k0)
+        for rid in self._held:
+            tr.end("held", rid, t=t)
+        self._held.clear()
+        return done
+
+    def _step(self, max_fuse: int) -> List[Request]:
         self._settle_resize()
-        self._try_admit()
+        if not self._phases:
+            self._try_admit()
+        else:
+            with self._phase("admit"):
+                self._try_admit()
         if self.prefill_budget is not None and self._prefill_fifo:
             self._advance_prefills()
         done_now: List[Request] = self._admit_done
@@ -2088,10 +2196,9 @@ class ContinuousBatchingScheduler:
                     lens[i] = 0             # garbage token lands on the sink
                     bt[i] = PC.SINK_PAGE    # page, masked out of attention
         outs, self.cache = self._timed(
-            "decode", self._decode_fn, self.params, self.cache,
-            jnp.asarray(toks), jnp.asarray(lens), jnp.asarray(bt), k=k,
-            tokens=k * len(decoding), ctx_tokens=int(np.sum(lens)))
-        outs = np.asarray(outs)             # (k, max_slots)
+            "decode", self._decode_fn, self.params, self.cache, toks, lens,
+            bt, k=k, tokens=k * len(decoding), ctx_tokens=int(np.sum(lens)))
+        outs = self._fetch(outs)            # (k, max_slots)
         self.stats["decode_steps"] += k
         self.step_idx += k                  # before _finish: finish_step must
         for slot in decoding:               # not depend on max_fuse

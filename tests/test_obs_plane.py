@@ -6,6 +6,7 @@ acceptance trace), histogram quantiles must agree with the bench's
 nearest-rank percentiles on real latencies, and the kernel profiler must
 report sane dispatch summaries."""
 import dataclasses
+import itertools
 import json
 
 import jax
@@ -15,7 +16,7 @@ import pytest
 from repro.configs.registry import REDUCED
 from repro.models import model as M
 from repro.obs.metrics import percentile
-from repro.obs.trace import Tracer
+from repro.obs.trace import LANE, Tracer
 from repro.serving.router import ServingRouter
 from repro.serving.scheduler import ContinuousBatchingScheduler
 
@@ -50,14 +51,24 @@ def _trace(cfg, seed, n=4, p_lo=3, p_hi=26, g_hi=6):
     return out
 
 
-def _run_sched(cfg, params, workload, *, observe=False):
+def _ticking_clock():
+    """A clock that moves one second on every reading."""
+    n = itertools.count()
+    return lambda: float(next(n))
+
+
+def _run_sched(cfg, params, workload, *, observe=False, clock=None,
+               prefill_budget=4, profile=None):
+    """``observe`` attaches a Tracer (on ``clock``, if given) and, unless
+    ``profile`` says otherwise, the kernel profiler."""
     sched = ContinuousBatchingScheduler(cfg, params, max_slots=3,
                                         page_size=8, max_seq_len=64,
-                                        prefill_budget=4)
+                                        prefill_budget=prefill_budget)
     tracer = profiler = None
     if observe:
-        tracer = Tracer()
+        tracer = Tracer(clock=clock)
         sched.set_tracer(tracer)
+    if observe if profile is None else profile:
         profiler = sched.enable_profiling()
     for i, (prompt, gen) in enumerate(workload):
         sched.submit(prompt, gen, arrival_step=i // 2)
@@ -83,6 +94,174 @@ def test_observed_run_emits_identical_tokens(arch):
     assert {s.name for s in tracer.spans} >= {"queued", "decode"}
     assert sched.h_latency.count == len(workload)
     assert profiler.summary()["decode"]["calls"] > 0
+    # the same on a wall clock, which also records every step's phases
+    walled, _, tracer, _ = _run_sched(cfg, params, workload, observe=True,
+                                      clock=_ticking_clock())
+    assert [list(r.out_tokens) for r in walled] == \
+        [list(r.out_tokens) for r in plain]
+    assert {s.name for s in tracer.spans} >= {
+        "queued", "prefill", "prefill_chunk", "held", "decode", "step",
+        "admit", "dispatch", "wait"}
+
+
+# ------------------------------------------------- wall-clock spans --
+
+@pytest.mark.parametrize("budget", (None, 4), ids=("monolithic", "chunked"))
+def test_wall_clock_spans_tile_submission_to_first_token(budget):
+    """Per request, queued -> prefill -> held follow one another with no
+    gap, from its submission to the end of the step() that returned its
+    first token; every step phase lies inside its step."""
+    cfg, params = _params("qwen3-32b")
+    workload = _trace(cfg, seed=5, n=6)
+    done, sched, tracer, _ = _run_sched(cfg, params, workload, observe=True,
+                                        clock=_ticking_clock(),
+                                        prefill_budget=budget)
+    assert len(done) == len(workload)
+    req = {(s.rid, s.name): s for s in tracer.spans if s.rid != LANE}
+    steps = [s for s in tracer.spans if s.name == "step"]
+    phases = [s for s in tracer.spans if s.rid == LANE and s.name != "step"]
+    assert {p.name for p in phases} == {"admit", "dispatch", "wait"}
+    for p in phases:
+        assert any(st.t0 < p.t0 and p.t1 < st.t1 for st in steps), p
+    for r in done:
+        q, pre, held = (req[r.rid, n] for n in ("queued", "prefill", "held"))
+        assert q.t0 < q.t1 == pre.t0 < pre.t1 == held.t0 < held.t1
+        # held ends with the step whose wait returned the first token
+        step = next(st for st in steps if st.t0 < pre.t1 < st.t1)
+        assert held.t1 == step.t1
+        assert pre.attrs["tokens"] == r.plen
+        chunks = [s for s in tracer.spans
+                  if s.rid == r.rid and s.name == "prefill_chunk"]
+        if budget is None:
+            assert not chunks
+        else:
+            # the prompt lands chunk after chunk, all inside the prefill
+            assert [c.attrs["pos"] for c in chunks] == list(
+                np.cumsum([0] + [c.attrs["tokens"] for c in chunks[:-1]]))
+            assert sum(c.attrs["tokens"] for c in chunks) == r.plen
+            assert all(pre.t0 < c.t0 < c.t1 < pre.t1 for c in chunks)
+
+
+def test_a_blocked_admission_names_its_reason_on_the_queued_span():
+    cfg, params = _params("qwen3-32b")
+    workload = _trace(cfg, seed=6, n=6)        # 6 requests, 3 slots
+    _, _, tracer, _ = _run_sched(cfg, params, workload, observe=True,
+                                 clock=_ticking_clock())
+    queued = [s for s in tracer.spans if s.name == "queued"]
+    assert any(s.attrs.get("blocked") == "slots" for s in queued)
+
+
+def test_wall_clock_tracing_adds_no_synchronisation(monkeypatch):
+    """The spans read the host clock only: the scheduler blocks on the
+    device and reads results back exactly as often with them as without."""
+    cfg, params = _params("qwen3-32b")
+    workload = _trace(cfg, seed=7)
+    counts = {}
+    orig_bur = jax.block_until_ready
+    orig_fetch = ContinuousBatchingScheduler._fetch
+
+    def count(key, fn):
+        def wrapped(*a, **k):
+            counts[key] = counts.get(key, 0) + 1
+            return fn(*a, **k)
+        return wrapped
+
+    def run(traced):
+        counts.clear()
+        monkeypatch.setattr(jax, "block_until_ready",
+                            count("block", orig_bur))
+        monkeypatch.setattr(ContinuousBatchingScheduler, "_fetch",
+                            count("fetch", orig_fetch))
+        sched = ContinuousBatchingScheduler(cfg, params, max_slots=3,
+                                            page_size=8, max_seq_len=64)
+        if traced:
+            sched.set_tracer(Tracer(clock=_ticking_clock()))
+        for i, (prompt, gen) in enumerate(workload):
+            sched.submit(prompt, gen, arrival_step=i // 2)
+        sched.run()
+        return dict(counts)
+
+    bare, traced = run(False), run(True)
+    assert traced.get("block", 0) <= bare.get("block", 0)
+    assert traced["fetch"] == bare["fetch"] > 0
+
+
+def test_without_a_tracer_a_step_makes_no_annotation(monkeypatch):
+    """Untraced and unprofiled, each site tests one flag and makes
+    nothing: no profiler annotation is even constructed, nor with a tracer
+    on the tick clock. With a wall-clock tracer the phases stand on the
+    profiler's host timeline as ``serving.<phase>``."""
+    cfg, params = _params("qwen3-32b")
+    workload = _trace(cfg, seed=8)
+
+    def refuse(*a, **k):
+        raise AssertionError("TraceAnnotation constructed")
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    plain, _, _, _ = _run_sched(cfg, params, workload)
+    ticked, _, _, _ = _run_sched(cfg, params, workload, observe=True,
+                                 profile=False)
+
+    names = []
+
+    class Recorder:
+        def __init__(self, name, **kw):
+            names.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    _run_sched(cfg, params, workload, observe=True, clock=_ticking_clock())
+    assert set(names) == {"serving.step", "serving.admit",
+                          "serving.dispatch", "serving.wait"}
+    assert [list(r.out_tokens) for r in ticked] == \
+        [list(r.out_tokens) for r in plain]
+
+
+def test_a_profiled_step_enters_its_phases_without_a_tracer(monkeypatch):
+    """``enable_profiling`` alone puts every step's phases on the
+    profiler's host timeline, nested step > admit/dispatch/wait, and the
+    tokens stay those of a bare run."""
+    cfg, params = _params("qwen3-32b")
+    workload = _trace(cfg, seed=9)
+    # monolithic prefill, so that admission dispatches and waits too
+    plain, _, _, _ = _run_sched(cfg, params, workload, prefill_budget=None)
+    stack, pairs = [], []
+
+    class Recorder:
+        def __init__(self, name, **kw):
+            self.name = name
+
+        def __enter__(self):
+            pairs.append((stack[-1] if stack else None, self.name))
+            stack.append(self.name)
+            return self
+
+        def __exit__(self, *exc):
+            assert stack.pop() == self.name
+            return False
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    profiled, sched, _, _ = _run_sched(cfg, params, workload, profile=True,
+                                       prefill_budget=None)
+    assert sched.tracer is None and not stack
+    assert set(pairs) == {(None, "serving.step"),
+                          ("serving.step", "serving.admit"),
+                          ("serving.step", "serving.dispatch"),
+                          ("serving.step", "serving.wait"),
+                          ("serving.admit", "serving.dispatch"),
+                          ("serving.admit", "serving.wait")}
+    assert [list(r.out_tokens) for r in profiled] == \
+        [list(r.out_tokens) for r in plain]
+
+
+def test_the_fleet_refuses_a_wall_clock_tracer():
+    cfg, params = _params("qwen3-32b")
+    router = ServingRouter(cfg, params, replicas=2, max_slots=2,
+                           page_size=8, max_seq_len=64)
+    with pytest.raises(ValueError, match="tick clock"):
+        router.set_tracer(Tracer(clock=_ticking_clock()))
 
 
 # -------------------------------------------------- disagg acceptance --

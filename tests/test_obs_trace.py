@@ -8,7 +8,7 @@ import sys
 import pytest
 
 from repro.core.events import EventLog
-from repro.obs.trace import TICK_US, Tracer
+from repro.obs.trace import LANE, TICK_US, Tracer
 
 
 def _sample_tracer():
@@ -59,6 +59,35 @@ def test_finish_open_flushes_with_marker():
     assert s.t1 == 8.0 and s.attrs["open"] is True
 
 
+def test_annotate_sets_attrs_of_an_open_span_only():
+    tr = Tracer()
+    tr.begin("queued", 4, t=0)
+    tr.annotate("queued", 4, blocked="slots")
+    tr.annotate("queued", 4, blocked="pages")     # the last reason stays
+    tr.annotate("queued", 5, blocked="slots")     # never opened: no-op
+    tr.end("queued", 4, t=2)
+    tr.annotate("queued", 4, blocked="quota")     # closed: no-op
+    assert [s.attrs for s in tr.spans] == [{"blocked": "pages"}]
+
+
+def test_wall_clock_stamps_every_hook_from_its_clock():
+    reads = iter([10.0, 10.5, 11.0, 12.0, 13.0])
+    tr = Tracer(clock=lambda: next(reads))
+    tr.set_tick(99)                            # ticks no longer stamp
+    tr.begin("queued", 1)                      # 10.0
+    tr.end("queued", 1)                        # 10.5
+    tr.instant("admitted", rid=1)              # 11.0
+    tr.begin("decode", 1, t=11.5)              # an explicit time is kept
+    assert tr.now() == 12.0
+    assert tr.finish_open() == 1               # 13.0
+    assert [(s.name, s.t0, s.t1) for s in tr.spans] == [
+        ("queued", 10.0, 10.5), ("decode", 11.5, 13.0)]
+    assert tr.instants[0].t == 11.0
+    tr.begin("held", 2, t=20.0)
+    tr.finish_open(t=25.0)                     # closed where it is told
+    assert (tr.spans[-1].t1, tr.spans[-1].attrs) == (25.0, {"open": True})
+
+
 # -------------------------------------------------------------- chrome --
 
 def test_chrome_export_shape():
@@ -78,6 +107,25 @@ def test_chrome_export_shape():
     auto = next(e for e in evs if e["name"] == "autoscale")
     assert auto["s"] == "g"                            # global instant
     json.dumps(doc)                                    # serializable
+
+
+def test_chrome_export_names_its_clock():
+    assert _sample_tracer().to_chrome()["otherData"] == {
+        "clock": f"sim tick = {TICK_US} us"}
+
+
+def test_chrome_export_on_a_wall_clock_renders_seconds():
+    tr = Tracer(clock=lambda: 3.0)
+    tr.span("prefill", 7, 2.0, 2.25, replica=0)
+    tr.span("step", LANE, 1.5, 2.5, replica=0, ticks=4)
+    tr.instant("admitted", rid=7, t=2.0, replica=0)
+    doc = tr.to_chrome()
+    assert "wall clock" in doc["otherData"]["clock"]
+    evs = {e["name"]: e for e in doc["traceEvents"] if e["ph"] != "M"}
+    assert evs["prefill"]["ts"] == 2.0e6 and evs["prefill"]["dur"] == 0.25e6
+    assert evs["step"]["tid"] == LANE and evs["step"]["dur"] == 1.0e6
+    assert evs["step"]["args"]["ticks"] == 4
+    assert evs["admitted"]["ts"] == 2.0e6
 
 
 def test_write_chrome_counts_events(tmp_path):
