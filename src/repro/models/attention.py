@@ -377,52 +377,81 @@ def gqa_decode(cfg: ModelConfig, p, x, cos, sin, cache: Dict[str, jnp.ndarray],
     return y, new_cache
 
 
+def _scatter_rows(pool: Dict[str, jnp.ndarray], lead: Tuple,
+                  page: jnp.ndarray, slot: jnp.ndarray,
+                  rows: Dict[str, jnp.ndarray],
+                  live: Optional[jnp.ndarray] = None) -> Dict[str, jnp.ndarray]:
+    """Scatter new K/V (and scale) rows into the pool leaves at
+    ``lead + (page, slot)``, in place. ``rows[name]`` is shaped like
+    ``page`` plus the leaf's trailing dims; with ``live`` set, dead rows
+    re-write the sink page's slot 0 with its own value, so scatter
+    duplicate-index resolution can't clobber a live slot."""
+    out = dict(pool)
+    for name, r in rows.items():
+        leaf = pool[name]
+        r = r.astype(leaf.dtype)
+        if live is not None:
+            keep = live.reshape(live.shape + (1,) * (r.ndim - live.ndim))
+            r = jnp.where(keep, r, leaf[lead + (0, 0)])
+        out[name] = leaf.at[lead + (page, slot)].set(r)
+    return out
+
+
+def _gather_pages(pool: Dict[str, jnp.ndarray], lead: Tuple,
+                  block_table: jnp.ndarray, dt):
+    """K and V of the ``block_table`` pages of the pool ``lead`` selects,
+    one gather per leaf, dequantised for int8/fp8 pools:
+    (B, n_pg * page_size, KVH', hd) each."""
+    idx = lead + (block_table,)
+    out = []
+    for name in ("k", "v"):
+        x = pool[f"{name}_pages"][idx]
+        if f"{name}_scale_pages" in pool:
+            x = (x.astype(dt)
+                 * pool[f"{name}_scale_pages"][idx][..., None].astype(dt))
+        out.append(x.reshape(x.shape[:1] + (-1,) + x.shape[-2:]))
+    return out
+
+
+def _quant_rows(cfg: ModelConfig, k_new: jnp.ndarray, v_new: jnp.ndarray):
+    """The rows a write lands: K/V as they are for a plain pool, quantised
+    values plus their fp32 scales for an int8/fp8 one."""
+    mode = kv_quant_mode(cfg)
+    if not mode:
+        return {"k_pages": k_new, "v_pages": v_new}
+    k8, ks = quantize_kv(k_new, mode)
+    v8, vs_ = quantize_kv(v_new, mode)
+    return {"k_pages": k8, "v_pages": v8,
+            "k_scale_pages": ks, "v_scale_pages": vs_}
+
+
 def _paged_write_attend(cfg: ModelConfig, pool: Dict[str, jnp.ndarray],
-                        q: jnp.ndarray, k_new: jnp.ndarray,
+                        lead: Tuple, q: jnp.ndarray, k_new: jnp.ndarray,
                         v_new: jnp.ndarray, seq_lens: jnp.ndarray,
                         block_table: jnp.ndarray, *, local: bool):
     """Write one decode token's K/V into its page and attend the pages.
 
-    The head-width-agnostic core of the paged decode step: ``pool`` holds
-    one pool's leaves (num_pages, page_size, KVH', hd) where KVH' is either
-    the full kv-head count (tp=1) or one shard's slice — the same function
-    serves both, which is what keeps the tensor-parallel path's math
-    identical to the unsharded one per head. q: (B,1,H',hd); k_new/v_new:
-    (B,1,KVH',hd). Returns (o (B,1,H',hd), new_pool).
+    The head-width-agnostic core of the paged decode step. ``pool`` holds
+    the pool leaves as the cache carries them — for a scanned layer the
+    whole stack, (n_periods, [tp,] num_pages, page_size, KVH', hd) — and
+    ``lead`` the indices that pick this layer's pool out of them: the
+    layer index, then the shard index on the tensor-parallel loop path;
+    () for a pool of its own. KVH' is the full kv-head count (tp=1) or one
+    shard's slice, which keeps the tensor-parallel math identical to the
+    unsharded one per head. The new row lands in place at
+    ``lead + (page, slot)`` and the pages are read by one gather at
+    ``lead + (block_table,)``: no op copies a pool out of the stack or
+    back in. q: (B,1,H',hd); k_new/v_new: (B,1,KVH',hd). Returns
+    (o (B,1,H',hd), new_pool), new_pool shaped like ``pool``.
     """
-    B = q.shape[0]
-    dt = q.dtype
-    ps = pool["k_pages"].shape[1]
-    n_pg = block_table.shape[1]
+    ps = pool["k_pages"].shape[len(lead) + 1]
     pos = seq_lens.astype(jnp.int32)                       # write position
     page = jnp.take_along_axis(block_table, (pos // ps)[:, None],
                                axis=1)[:, 0]
     slot = pos % ps
-    if cfg.cache_quant:
-        mode = kv_quant_mode(cfg)
-        k8, ks = quantize_kv(k_new, mode)
-        v8, vs_ = quantize_kv(v_new, mode)
-        k_pages = pool["k_pages"].at[page, slot].set(k8[:, 0])
-        v_pages = pool["v_pages"].at[page, slot].set(v8[:, 0])
-        k_sc = pool["k_scale_pages"].at[page, slot].set(ks[:, 0])
-        v_sc = pool["v_scale_pages"].at[page, slot].set(vs_[:, 0])
-        k_deq = (k_pages[block_table].astype(dt)
-                 * k_sc[block_table][..., None].astype(dt))
-        v_deq = (v_pages[block_table].astype(dt)
-                 * v_sc[block_table][..., None].astype(dt))
-        new_pool = {"k_pages": k_pages, "v_pages": v_pages,
-                    "k_scale_pages": k_sc, "v_scale_pages": v_sc}
-    else:
-        k_pages = pool["k_pages"].at[page, slot].set(k_new[:, 0].astype(
-            pool["k_pages"].dtype))
-        v_pages = pool["v_pages"].at[page, slot].set(v_new[:, 0].astype(
-            pool["v_pages"].dtype))
-        k_deq = k_pages[block_table]
-        v_deq = v_pages[block_table]
-        new_pool = {"k_pages": k_pages, "v_pages": v_pages}
-    KVH, hd = k_deq.shape[-2], k_deq.shape[-1]
-    k_deq = k_deq.reshape(B, n_pg * ps, KVH, hd)
-    v_deq = v_deq.reshape(B, n_pg * ps, KVH, hd)
+    rows = {k: v[:, 0] for k, v in _quant_rows(cfg, k_new, v_new).items()}
+    new_pool = _scatter_rows(pool, lead, page, slot, rows)
+    k_deq, v_deq = _gather_pages(new_pool, lead, block_table, q.dtype)
     valid = pos + 1
     start = None
     if local and cfg.sliding_window:
@@ -458,34 +487,34 @@ def shard_gqa_params(cfg: ModelConfig, p, s: int, tp: int):
     return out
 
 
-def _gqa_paged_decode_loop(cfg, p, x, cos, sin, cache, seq_lens,
+def _gqa_paged_decode_loop(cfg, p, x, cos, sin, cache, lead, seq_lens,
                            block_table, *, local, tp):
     """Unrolled shard-group decode: the per-shard body runs ``tp`` times in
-    one program (single-host simulation of the shard_map layout)."""
+    one program (single-host simulation of the shard_map layout), each
+    writing and reading its own slice ``lead + (s,)`` of the carried
+    pools."""
     B = x.shape[0]
     Hs = cfg.n_heads // tp
     KVHs = cfg.n_kv_heads // tp
-    o_parts, pools = [], []
+    o_parts = []
     for s in range(tp):
         p_s = shard_gqa_params(cfg, p, s, tp)
-        pool_s = {k: v[s] for k, v in cache.items()}
         q, k_new, v_new = _qkv(cfg, p_s, x, cos, sin,
                                n_heads=Hs, n_kv_heads=KVHs)
-        o_s, pool_s = _paged_write_attend(cfg, pool_s, q, k_new, v_new,
-                                          seq_lens, block_table, local=local)
+        o_s, cache = _paged_write_attend(cfg, cache, lead + (s,), q, k_new,
+                                         v_new, seq_lens, block_table,
+                                         local=local)
         o_parts.append(o_s)
-        pools.append(pool_s)
     o = jnp.concatenate(o_parts, axis=2)         # head-axis "all_gather"
-    new_cache = {k: jnp.stack([pools[s][k] for s in range(tp)])
-                 for k in cache}
     y = o.reshape(B, 1, -1) @ p["wo"].astype(x.dtype)
-    return y, new_cache
+    return y, cache
 
 
-def _gqa_paged_decode_shard_map(cfg, p, x, cos, sin, cache, seq_lens,
+def _gqa_paged_decode_shard_map(cfg, p, x, cos, sin, cache, lead, seq_lens,
                                 block_table, *, local, shard):
     """Shard-group decode as one program per device: pools and projection
-    weights partition on the group's mesh axis, the per-shard body is the
+    weights partition on the group's mesh axis (the pools' shard axis,
+    after the layer axis of a stacked pool), the per-shard body is the
     same ``_qkv`` + ``_paged_write_attend`` the loop path runs, and the
     only wire traffic is the tiny (B,1,H,hd) head all_gather before the
     replicated output projection."""
@@ -504,25 +533,25 @@ def _gqa_paged_decode_shard_map(cfg, p, x, cos, sin, cache, seq_lens,
             sliced[k] = p[k].reshape(tp, -1)
             sliced_specs[k] = P(ax, None)
     repl = {k: p[k] for k in ("q_norm", "k_norm") if cfg.qk_norm}
-    pool_specs = {k: P(ax) for k in cache}
+    pool_specs = {k: P(*(None,) * len(lead), ax) for k in cache}
+    lead = tuple(jnp.asarray(i, jnp.int32) for i in lead)
 
-    def body(sl, rp, pool, xx, cc, ss, lens, bt):
+    def body(sl, rp, pool, ld, xx, cc, ss, lens, bt):
         p_s = {k: v[:, 0] if v.ndim == 3 else v[0] for k, v in sl.items()}
         p_s.update(rp)
-        pool_s = {k: v[0] for k, v in pool.items()}
         q, k_new, v_new = _qkv(cfg, p_s, xx, cc, ss,
                                n_heads=Hs, n_kv_heads=KVHs)
-        o_s, pool_s = _paged_write_attend(cfg, pool_s, q, k_new, v_new,
-                                          lens, bt, local=local)
+        o_s, pool = _paged_write_attend(cfg, pool, ld + (0,), q, k_new,
+                                        v_new, lens, bt, local=local)
         o = jax.lax.all_gather(o_s, ax, axis=2, tiled=True)  # (B,1,H,hd)
-        return o, {k: v[None] for k, v in pool_s.items()}
+        return o, pool
 
     fn = jax.shard_map(
         body, mesh=shard.mesh,
         in_specs=(sliced_specs, {k: P() for k in repl}, pool_specs,
-                  P(), P(), P(), P(), P()),
+                  P(), P(), P(), P(), P(), P()),
         out_specs=(P(), pool_specs), check_vma=False)
-    o, new_cache = fn(sliced, repl, cache, x, cos, sin, seq_lens,
+    o, new_cache = fn(sliced, repl, cache, lead, x, cos, sin, seq_lens,
                       block_table.astype(jnp.int32))
     y = o.reshape(B, 1, -1) @ p["wo"].astype(x.dtype)
     return y, new_cache
@@ -530,14 +559,19 @@ def _gqa_paged_decode_shard_map(cfg, p, x, cos, sin, cache, seq_lens,
 
 def gqa_paged_decode(cfg: ModelConfig, p, x, cos, sin,
                      cache: Dict[str, jnp.ndarray], seq_lens: jnp.ndarray,
-                     block_table: jnp.ndarray, *, local: bool, shard=None):
+                     block_table: jnp.ndarray, *, local: bool, shard=None,
+                     layer=None):
     """Paged-KV decode step: write the new token's K/V into its page, then
     attend the sequence's pages via the block table.
 
     x: (B,1,D); seq_lens: (B,) live token counts (the new token lands at
     position ``seq_lens[b]``); block_table: (B, n_pg) page ids into the
     layer's pools ``cache["k_pages"]``/``cache["v_pages"]`` of shape
-    (num_pages, page_size, KVH, hd). This is the pure-XLA path (CPU smoke
+    (num_pages, page_size, KVH, hd). Inside the layer scan ``cache``
+    holds the whole stack of a period position's pools, with a leading
+    layers axis, and ``layer`` is the scanned layer's index into it: the
+    step then writes and reads that layer's pages in place (see
+    ``_paged_write_attend``). This is the pure-XLA path (CPU smoke
     tests / dry-run); ``repro.kernels.paged_decode`` computes the same
     function on TPU without materialising the gathered cache. Sliding-window
     layers mask ``[len+1-window, len]`` instead of ring-writing — pages hold
@@ -545,20 +579,23 @@ def gqa_paged_decode(cfg: ModelConfig, p, x, cos, sin,
 
     ``shard`` (a ``repro.parallel.context.ShardGroup`` with tp > 1) runs
     the head-sharded tensor-parallel path instead: pool leaves carry a
-    leading shard axis, each shard computes its query/kv head slice against
-    its own pool slice, and the head-axis concat + full output projection
-    keep the result byte-identical to tp=1 (see docs/sharding.md).
+    shard axis (after the layers axis), each shard computes its query/kv
+    head slice against its own pool slice, and the head-axis concat + full
+    output projection keep the result byte-identical to tp=1 (see
+    docs/sharding.md).
     """
+    lead = () if layer is None else (layer,)
     if shard is not None and shard.tp > 1:
         if shard.use_shard_map:
             return _gqa_paged_decode_shard_map(
-                cfg, p, x, cos, sin, cache, seq_lens, block_table,
+                cfg, p, x, cos, sin, cache, lead, seq_lens, block_table,
                 local=local, shard=shard)
-        return _gqa_paged_decode_loop(cfg, p, x, cos, sin, cache, seq_lens,
-                                      block_table, local=local, tp=shard.tp)
+        return _gqa_paged_decode_loop(cfg, p, x, cos, sin, cache, lead,
+                                      seq_lens, block_table, local=local,
+                                      tp=shard.tp)
     B = x.shape[0]
     q, k_new, v_new = _qkv(cfg, p, x, cos, sin)
-    o, new_cache = _paged_write_attend(cfg, cache, q, k_new, v_new,
+    o, new_cache = _paged_write_attend(cfg, cache, lead, q, k_new, v_new,
                                        seq_lens, block_table, local=local)
     y = o.reshape(B, 1, -1) @ p["wo"].astype(x.dtype)
     return y, new_cache
@@ -591,12 +628,14 @@ def _chunk_attend(q, k, v, *, q_abs, total, window, softcap, scale=None):
 
 
 def _paged_prefill_write_attend(cfg: ModelConfig, pool: Dict[str, jnp.ndarray],
-                                q: jnp.ndarray, k_new: jnp.ndarray,
-                                v_new: jnp.ndarray, start: jnp.ndarray,
-                                chunk_len: jnp.ndarray,
+                                lead: Tuple, q: jnp.ndarray,
+                                k_new: jnp.ndarray, v_new: jnp.ndarray,
+                                start: jnp.ndarray, chunk_len: jnp.ndarray,
                                 block_table: jnp.ndarray, *, local: bool):
     """Write one prompt chunk's K/V into its pages, then attend
-    prefix+chunk — the chunk-width sibling of ``_paged_write_attend``.
+    prefix+chunk — the chunk-width sibling of ``_paged_write_attend``,
+    with the same ``pool``/``lead`` convention (the chunk lands in place
+    at ``lead + (page, slot)``).
 
     q: (B,S,H',hd); k_new/v_new: (B,S,KVH',hd) — chunk token ``t`` lands
     at absolute position ``start[b] + t``; rows past ``chunk_len[b]`` are
@@ -611,71 +650,42 @@ def _paged_prefill_write_attend(cfg: ModelConfig, pool: Dict[str, jnp.ndarray],
 
     With ``repro.models.flags.prefill_kernel()`` set (a trace-time flag)
     the Pallas write+attend pair from ``repro.kernels.paged_prefill``
-    computes the same function without materialising the gathered cache.
+    computes the same function without materialising the gathered cache;
+    the kernels take one layer's pool, so that path slices it out of the
+    stack and writes it back.
     """
     from repro.models import flags
-    B, S = q.shape[0], q.shape[1]
+    S = q.shape[1]
     dt = q.dtype
-    mode = kv_quant_mode(cfg)
     window = cfg.sliding_window if (local and cfg.sliding_window) else None
     if flags.prefill_kernel():
         from repro.kernels import ops as kops
-        o, new_pool = kops.paged_prefill(
-            q, k_new, v_new, pool, block_table, start, chunk_len,
-            quant=mode, softcap=cfg.attn_softcap, window=window)
+        layer_pool = {k: v[lead] for k, v in pool.items()}
+        o, layer_pool = kops.paged_prefill(
+            q, k_new, v_new, layer_pool, block_table, start, chunk_len,
+            quant=kv_quant_mode(cfg), softcap=cfg.attn_softcap,
+            window=window)
+        new_pool = {k: v.at[lead].set(layer_pool[k]) if lead
+                    else layer_pool[k] for k, v in pool.items()}
         return o.astype(dt), new_pool
-    ps = pool["k_pages"].shape[1]
+    ps = pool["k_pages"].shape[len(lead) + 1]
     n_pg = block_table.shape[1]
     pos = start[:, None] + jnp.arange(S, dtype=jnp.int32)[None]     # (B,S)
     live = jnp.arange(S, dtype=jnp.int32)[None] < chunk_len[:, None]
-    # dead rows route to the sink page's slot 0 re-writing its own value,
-    # so scatter duplicate-index resolution can't clobber a live slot
     pg_idx = jnp.clip(pos // ps, 0, n_pg - 1)
     page = jnp.where(live, jnp.take_along_axis(block_table, pg_idx, axis=1), 0)
     slot = jnp.where(live, pos % ps, 0)
-    if mode:
-        k8, ks = quantize_kv(k_new, mode)
-        v8, vs_ = quantize_kv(v_new, mode)
-        sink_k = pool["k_pages"][0, 0]
-        sink_v = pool["v_pages"][0, 0]
-        sink_ks = pool["k_scale_pages"][0, 0]
-        sink_vs = pool["v_scale_pages"][0, 0]
-        k8 = jnp.where(live[..., None, None], k8, sink_k)
-        v8 = jnp.where(live[..., None, None], v8, sink_v)
-        ks = jnp.where(live[..., None], ks, sink_ks)
-        vs_ = jnp.where(live[..., None], vs_, sink_vs)
-        k_pages = pool["k_pages"].at[page, slot].set(k8)
-        v_pages = pool["v_pages"].at[page, slot].set(v8)
-        k_sc = pool["k_scale_pages"].at[page, slot].set(ks)
-        v_sc = pool["v_scale_pages"].at[page, slot].set(vs_)
-        k_deq = (k_pages[block_table].astype(dt)
-                 * k_sc[block_table][..., None].astype(dt))
-        v_deq = (v_pages[block_table].astype(dt)
-                 * v_sc[block_table][..., None].astype(dt))
-        new_pool = {"k_pages": k_pages, "v_pages": v_pages,
-                    "k_scale_pages": k_sc, "v_scale_pages": v_sc}
-    else:
-        pdt = pool["k_pages"].dtype
-        sink_k = pool["k_pages"][0, 0]
-        sink_v = pool["v_pages"][0, 0]
-        kw = jnp.where(live[..., None, None], k_new.astype(pdt), sink_k)
-        vw = jnp.where(live[..., None, None], v_new.astype(pdt), sink_v)
-        k_pages = pool["k_pages"].at[page, slot].set(kw)
-        v_pages = pool["v_pages"].at[page, slot].set(vw)
-        k_deq = k_pages[block_table]
-        v_deq = v_pages[block_table]
-        new_pool = {"k_pages": k_pages, "v_pages": v_pages}
-    KVH, hd = k_deq.shape[-2], k_deq.shape[-1]
-    k_deq = k_deq.reshape(B, n_pg * ps, KVH, hd)
-    v_deq = v_deq.reshape(B, n_pg * ps, KVH, hd)
+    new_pool = _scatter_rows(pool, lead, page, slot,
+                             _quant_rows(cfg, k_new, v_new), live=live)
+    k_deq, v_deq = _gather_pages(new_pool, lead, block_table, dt)
     o = _chunk_attend(q, k_deq, v_deq, q_abs=pos,
                       total=start + chunk_len, window=window,
                       softcap=cfg.attn_softcap)
     return o, new_pool
 
 
-def _gqa_paged_prefill_loop(cfg, p, x, cos, sin, cache, start, chunk_len,
-                            block_table, *, local, tp):
+def _gqa_paged_prefill_loop(cfg, p, x, cos, sin, cache, lead, start,
+                            chunk_len, block_table, *, local, tp):
     """Unrolled shard-group fused prefill: the per-shard body runs ``tp``
     times in one program (mirrors ``_gqa_paged_decode_loop`` — prefill
     always takes the loop path; chunk dispatches are rare enough that a
@@ -683,46 +693,44 @@ def _gqa_paged_prefill_loop(cfg, p, x, cos, sin, cache, start, chunk_len,
     B, S = x.shape[:2]
     Hs = cfg.n_heads // tp
     KVHs = cfg.n_kv_heads // tp
-    o_parts, pools = [], []
+    o_parts = []
     for s in range(tp):
         p_s = shard_gqa_params(cfg, p, s, tp)
-        pool_s = {k: v[s] for k, v in cache.items()}
         q, k_new, v_new = _qkv(cfg, p_s, x, cos, sin,
                                n_heads=Hs, n_kv_heads=KVHs)
-        o_s, pool_s = _paged_prefill_write_attend(
-            cfg, pool_s, q, k_new, v_new, start, chunk_len, block_table,
-            local=local)
+        o_s, cache = _paged_prefill_write_attend(
+            cfg, cache, lead + (s,), q, k_new, v_new, start, chunk_len,
+            block_table, local=local)
         o_parts.append(o_s)
-        pools.append(pool_s)
     o = jnp.concatenate(o_parts, axis=2)         # head-axis "all_gather"
-    new_cache = {k: jnp.stack([pools[s][k] for s in range(tp)])
-                 for k in cache}
     y = o.reshape(B, S, -1) @ p["wo"].astype(x.dtype)
-    return y, new_cache
+    return y, cache
 
 
 def gqa_paged_prefill(cfg: ModelConfig, p, x, cos, sin,
                       cache: Dict[str, jnp.ndarray], start: jnp.ndarray,
                       chunk_len: jnp.ndarray, block_table: jnp.ndarray, *,
-                      local: bool, shard=None):
+                      local: bool, shard=None, layer=None):
     """Fused chunked-prefill step: write the chunk's K/V directly into its
     pages and attend prefix+chunk in one pass — no dense intermediate, no
     post-hoc ``write_prefill`` copy.
 
     x: (B,S,D) chunk hidden states; start: (B,) tokens already in the
-    pages; chunk_len: (B,) live rows of this chunk; block_table: (B,n_pg).
-    Returns (y (B,S,D), new_cache). ``shard`` with tp > 1 runs the
-    head-sharded loop path (byte-identical to tp=1, like decode).
+    pages; chunk_len: (B,) live rows of this chunk; block_table: (B,n_pg);
+    ``cache``/``layer`` as in ``gqa_paged_decode``. Returns (y (B,S,D),
+    new_cache). ``shard`` with tp > 1 runs the head-sharded loop path
+    (byte-identical to tp=1, like decode).
     """
+    lead = () if layer is None else (layer,)
     if shard is not None and shard.tp > 1:
-        return _gqa_paged_prefill_loop(cfg, p, x, cos, sin, cache, start,
-                                       chunk_len, block_table, local=local,
-                                       tp=shard.tp)
+        return _gqa_paged_prefill_loop(cfg, p, x, cos, sin, cache, lead,
+                                       start, chunk_len, block_table,
+                                       local=local, tp=shard.tp)
     B, S = x.shape[:2]
     q, k_new, v_new = _qkv(cfg, p, x, cos, sin)
-    o, new_cache = _paged_prefill_write_attend(cfg, cache, q, k_new, v_new,
-                                               start, chunk_len, block_table,
-                                               local=local)
+    o, new_cache = _paged_prefill_write_attend(cfg, cache, lead, q, k_new,
+                                               v_new, start, chunk_len,
+                                               block_table, local=local)
     y = o.reshape(B, S, -1) @ p["wo"].astype(x.dtype)
     return y, new_cache
 
@@ -865,21 +873,22 @@ def attn_decode(cfg, p, x, cos, sin, cache, cur_len, *, local=False):
 
 
 def attn_paged_decode(cfg, p, x, cos, sin, cache, seq_lens, block_table, *,
-                      local=False, shard=None):
+                      local=False, shard=None, layer=None):
     if cfg.attn_impl == "mla":
         raise NotImplementedError(
             "paged decode covers GQA; MLA serves via the dense absorbed path")
     return gqa_paged_decode(cfg, p, x, cos, sin, cache, seq_lens, block_table,
-                            local=local, shard=shard)
+                            local=local, shard=shard, layer=layer)
 
 
 def attn_paged_prefill(cfg, p, x, cos, sin, cache, start, chunk_len,
-                       block_table, *, local=False, shard=None):
+                       block_table, *, local=False, shard=None, layer=None):
     if cfg.attn_impl == "mla":
         raise NotImplementedError(
             "fused paged prefill covers GQA; MLA serves via the dense path")
     return gqa_paged_prefill(cfg, p, x, cos, sin, cache, start, chunk_len,
-                             block_table, local=local, shard=shard)
+                             block_table, local=local, shard=shard,
+                             layer=layer)
 
 
 def kv_cache_spec(cfg: ModelConfig, batch: int, capacity: int,

@@ -87,8 +87,12 @@ def block_apply(cfg: ModelConfig, p: Dict[str, Any], x: jnp.ndarray, idx: int,
                 cos, sin, mode: str, cache: Optional[Dict] = None,
                 cur_len: Optional[jnp.ndarray] = None,
                 block_table: Optional[jnp.ndarray] = None,
-                shard=None, chunk_len: Optional[jnp.ndarray] = None):
-    """-> (x, aux, cache_update). ``shard`` (a ShardGroup) activates the
+                shard=None, chunk_len: Optional[jnp.ndarray] = None,
+                layer: Optional[jnp.ndarray] = None):
+    """-> (x, aux, cache_update). ``layer`` (paged modes, inside the layer
+    scan): ``cache`` holds the whole stack of this period position's
+    leaves and ``layer`` indexes it; the block updates its own layer's
+    rows in place and returns the stack. ``shard`` (a ShardGroup) activates the
     tensor-parallel paged-decode path: head-sharded attention over per-shard
     page pools, expert-sharded MoE; SSM mixers stay replicated (their state
     is O(1) per sequence — nothing to split). ``mode == "paged_prefill"``
@@ -109,8 +113,13 @@ def block_apply(cfg: ModelConfig, p: Dict[str, Any], x: jnp.ndarray, idx: int,
             mix = ssm_mod.ssm_train(cfg, p["mixer"], h)
         elif mode == "prefill":
             mix, cache_update = ssm_mod.ssm_prefill(cfg, p["mixer"], h)
-        else:
+        elif layer is None:
             mix, cache_update = ssm_mod.ssm_decode(cfg, p["mixer"], h, cache)
+        else:
+            mix, state = ssm_mod.ssm_decode(
+                cfg, p["mixer"], h, {k: v[layer] for k, v in cache.items()})
+            cache_update = {k: jax.lax.dynamic_update_index_in_dim(
+                v, state[k], layer, 0) for k, v in cache.items()}
     else:
         if mode == "train":
             mix = attn.attn_train(cfg, p["mixer"], h, cos, sin, local=local)
@@ -120,11 +129,11 @@ def block_apply(cfg: ModelConfig, p: Dict[str, Any], x: jnp.ndarray, idx: int,
         elif mode == "paged_decode":
             mix, cache_update = attn.attn_paged_decode(
                 cfg, p["mixer"], h, cos, sin, cache, cur_len, block_table,
-                local=local, shard=shard)
+                local=local, shard=shard, layer=layer)
         elif mode == "paged_prefill":
             mix, cache_update = attn.attn_paged_prefill(
                 cfg, p["mixer"], h, cos, sin, cache, cur_len, chunk_len,
-                block_table, local=local, shard=shard)
+                block_table, local=local, shard=shard, layer=layer)
         else:
             mix, cache_update = attn.attn_decode(cfg, p["mixer"], h, cos, sin,
                                                  cache, cur_len, local=local)
@@ -192,8 +201,9 @@ def lm_forward(cfg: ModelConfig, params: Dict[str, Any], tokens: jnp.ndarray,
         sequence to its pages — this is what lets the continuous-batching
         scheduler decode sequences of different lengths in one step.
         ``shard`` (a ``repro.parallel.context.ShardGroup``, tp > 1) selects
-        the tensor-parallel path: pool leaves carry a leading shard axis
-        and attention/MoE split across the group (docs/sharding.md).
+        the tensor-parallel path: pool leaves carry a shard axis (behind
+        the stack's layers axis) and attention/MoE split across the group
+        (docs/sharding.md).
     paged_prefill -> (hidden, aux, cache)  tokens: (B, S) one prompt chunk
         per sequence; ``cur_len`` (B,) tokens already landed in the pages
         (chunk row t sits at absolute position cur_len+t), ``chunk_len``
@@ -280,20 +290,42 @@ def lm_forward(cfg: ModelConfig, params: Dict[str, Any], tokens: jnp.ndarray,
         if prefix_cache_out:
             cache_out["prefix"] = prefix_cache_out
 
-    else:  # decode / paged_decode / paged_prefill
+    elif mode == "decode":
         def body(xx, xs_p):
             ps, cs = xs_p
             new_cs = {}
             for p in range(period):
                 xx, _, c_out = block_apply(cfg, ps[str(p)], xx, prefix + p,
                                            cos, sin, mode, cs[str(p)],
-                                           cur_len, block_table, shard,
-                                           chunk_len=chunk_len)
+                                           cur_len)
                 new_cs[str(p)] = c_out
             return xx, new_cs
 
         x, stack_cache = _maybe_scan(body, x, (stack_params, cache["stack"]),
                                      n_periods)
+        cache_out = {"stack": stack_cache}
+        if prefix_cache_out:
+            cache_out["prefix"] = prefix_cache_out
+
+    else:  # paged_decode / paged_prefill
+        # The stacked page pools ride in the scan's carry and each layer
+        # updates its own rows in place at [layer, page, slot]: as scan
+        # xs/ys every layer's pool would be sliced out of the stack and
+        # restacked into a fresh one, a copy of the whole pool per tick.
+        def body(carry, xs_p):
+            xx, cs = carry
+            ps, layer = xs_p
+            cs = dict(cs)
+            for p in range(period):
+                xx, _, cs[str(p)] = block_apply(
+                    cfg, ps[str(p)], xx, prefix + p, cos, sin, mode,
+                    cs[str(p)], cur_len, block_table, shard,
+                    chunk_len=chunk_len, layer=layer)
+            return (xx, cs), None
+
+        layers = jnp.arange(n_periods, dtype=jnp.int32)
+        (x, stack_cache), _ = _maybe_scan(
+            body, (x, cache["stack"]), (stack_params, layers), n_periods)
         cache_out = {"stack": stack_cache}
         if prefix_cache_out:
             cache_out["prefix"] = prefix_cache_out
