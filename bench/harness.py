@@ -17,8 +17,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from bench import serving_stats, traffic
-from bench.reference import arch_of
+from bench import families, serving_stats, traffic
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 PAGE_SIZE = 16
@@ -87,19 +86,13 @@ def load_json(rel: str) -> dict:
 
 
 def program_config(conf: dict):
-    """The program's ModelConfig for a configuration file, checked against
-    the published widths the reference reads."""
+    """The program's ModelConfig for a configuration file, checked by its
+    family against the published widths the reference reads."""
     from repro.configs.registry import get_arch
     prog = dict(conf["program"])
     cfg = dataclasses.replace(get_arch(prog.pop("arch")), **prog)
-    a = arch_of(conf)
-    got = (cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim,
-           cfg.d_ff, cfg.vocab_size, cfg.n_layers, cfg.qkv_bias, cfg.qk_norm)
-    want = (a.d, a.heads, a.kv_heads, a.head_dim, a.ff, a.vocab, a.layers,
-            a.qkv_bias, a.qk_norm)
-    if got != want:
-        raise ValueError(f"program config {got} differs from the published "
-                         f"configuration {want}")
+    fam = families.of(conf)
+    fam.check_program(cfg, fam.arch(conf))
     return cfg
 
 
@@ -131,13 +124,15 @@ def setup(conf: dict, mix: dict, seed: int):
     ``(params, sched, weights_s, warm_up_s, warm_up_requests)``."""
     import jax
     from bench import weights
+    fam = families.of(conf)
     cfg = program_config(conf)
     t = time.perf_counter()
-    params = jax.block_until_ready(weights.make(param_shapes(cfg), seed))
+    params = jax.block_until_ready(weights.make(param_shapes(cfg), seed,
+                                                fam.LEAVES))
     t_weights = time.perf_counter() - t
     t = time.perf_counter()
     sched = build_scheduler(cfg, params, mix)
-    warm = warm_up(sched, mix, seed, arch_of(conf).vocab)
+    warm = warm_up(sched, mix, seed, fam.arch(conf).vocab)
     if len(sched.index):
         raise RuntimeError("prefix index not empty after warm-up")
     return params, sched, t_weights, time.perf_counter() - t, warm

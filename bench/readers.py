@@ -23,10 +23,11 @@ PROGRAM = {"decode": r"^jit__unknown\b", "prefill": r"^jit_fn\b"}
 
 
 def work(ctx, d) -> Tuple[int, int]:
-    """(FLOPs, bytes) of one logged dispatch."""
+    """(FLOPs, bytes) of one logged dispatch, as the configuration's
+    family counts them."""
     if d.kind in DECODE:
-        return roofline.decode_work(ctx.arch, d.k, d.tokens // d.k, d.ctx)
-    return roofline.prefill_work(ctx.arch, d.tokens, d.ctx)
+        return ctx.family.decode_work(ctx.arch, d.k, d.tokens // d.k, d.ctx)
+    return ctx.family.prefill_work(ctx.arch, d.tokens, d.ctx)
 
 
 def of_kind(ctx, kinds) -> list:

@@ -1,27 +1,16 @@
-"""Operations and bytes the served model needs, and the chips' peaks.
+"""The chips' peaks, and the least time a dispatch's work needs on them.
 
-Work is counted from the published architecture and the live lengths, so
-it is the same whatever implements it: empty slots, padding rows and pages,
-and copies of pages gathered for attention are never work. Weights are
-bfloat16 (2 bytes); the K/V cache holds bfloat16 keys and values.
-
-* FLOPs: 2 per multiply-add of every linear layer for every token computed,
-  4 * heads * head_dim per (query, visible key) pair per layer for the two
-  attention products, and 2 * d * vocab for every row whose logits are
-  computed (every decode row; the last row of a prefill dispatch).
-* Bytes: every weight once per dispatched step (the embedding only for the
-  rows looked up), the K/V of the live context read once, and the new
-  tokens' K/V written once.
+A dispatch's work, (FLOPs, bytes), is its configuration family's to count
+(``decode_work`` and ``prefill_work`` in ``bench/families/<model_type>.py``),
+from the published architecture and the live lengths, so it is the same
+whatever implements it: empty slots, padding rows and pages, and copies of
+pages gathered for attention are never work. FLOPs count 2 per
+multiply-add; bytes count what the step has to move to and from HBM once.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Tuple
-
-from bench.reference import Arch
-
-W_BYTES = 2
-KV_BYTES = 2
+from typing import Dict
 
 
 @dataclasses.dataclass(frozen=True)
@@ -47,65 +36,6 @@ def peaks(device_kind: str) -> Peaks:
         raise KeyError(f"no published peaks for device kind {device_kind!r} "
                        f"(known: {sorted(DEVICE_PEAKS)})")
     return DEVICE_PEAKS[device_kind]
-
-
-def layer_params(a: Arch) -> int:
-    """Weights of one decoder layer (norms and biases included)."""
-    q, kv = a.heads * a.head_dim, a.kv_heads * a.head_dim
-    n = a.d * q + 2 * a.d * kv + q * a.d + 3 * a.d * a.ff + 2 * a.d
-    if a.qkv_bias:
-        n += q + 2 * kv
-    if a.qk_norm:
-        n += 2 * a.head_dim
-    return n
-
-
-def linear_flops_per_token(a: Arch) -> int:
-    q, kv = a.heads * a.head_dim, a.kv_heads * a.head_dim
-    return 2 * a.layers * (a.d * q + 2 * a.d * kv + q * a.d + 3 * a.d * a.ff)
-
-
-def head_flops_per_row(a: Arch) -> int:
-    return 2 * a.d * a.vocab
-
-
-def attn_flops(a: Arch, pairs: int) -> int:
-    """``pairs`` (query, visible key) pairs through every layer."""
-    return 4 * a.layers * a.heads * a.head_dim * pairs
-
-
-def weight_bytes(a: Arch) -> int:
-    """Weights one step reads: every layer, the final norm and the head."""
-    return W_BYTES * (a.layers * layer_params(a) + a.d + a.d * a.vocab)
-
-
-def kv_bytes_per_token(a: Arch) -> int:
-    return KV_BYTES * 2 * a.layers * a.kv_heads * a.head_dim
-
-
-def decode_work(a: Arch, k: int, rows: int, ctx: int) -> Tuple[int, int]:
-    """(FLOPs, bytes) of ``k`` fused decode ticks of ``rows`` sequences
-    whose live lengths sum to ``ctx`` before the first tick. Tick j's row i
-    attends its len_i + j + 1 keys."""
-    pairs = k * ctx + rows * k * (k + 1) // 2
-    toks = k * rows
-    flops = toks * (linear_flops_per_token(a) + head_flops_per_row(a)) \
-        + attn_flops(a, pairs)
-    byt = k * weight_bytes(a) + toks * W_BYTES * a.d \
-        + (pairs - toks) * kv_bytes_per_token(a) \
-        + toks * kv_bytes_per_token(a)
-    return flops, byt
-
-
-def prefill_work(a: Arch, s: int, start: int) -> Tuple[int, int]:
-    """(FLOPs, bytes) of one prefill dispatch: ``s`` prompt tokens landed
-    after ``start`` tokens already in the cache, logits for the last row."""
-    pairs = s * start + s * (s + 1) // 2
-    flops = s * linear_flops_per_token(a) + attn_flops(a, pairs) \
-        + head_flops_per_row(a)
-    byt = weight_bytes(a) + s * W_BYTES * a.d \
-        + start * kv_bytes_per_token(a) + s * kv_bytes_per_token(a)
-    return flops, byt
 
 
 def least_time(flops: int, byt: int, p: Peaks) -> float:

@@ -34,8 +34,9 @@ for p in (ROOT, ROOT / "src"):
     if str(p) not in sys.path:
         sys.path.insert(0, str(p))
 
-from bench import harness, roofline, serving_stats, traffic  # noqa: E402
-from bench.reference import arch_of, control_tokens, token_gaps  # noqa: E402
+from bench import (families, harness, roofline, serving_stats,  # noqa: E402
+                   traffic)
+from bench.reference import control_tokens, token_gaps  # noqa: E402
 
 TRACE_DIR = ROOT / ".bench_trace"
 
@@ -104,7 +105,8 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
     count = harness.CompileCount()
     log(f"[setup] {workload} seed {seed} on {len(devices)} x "
         f"{dev.device_kind}; compile cache {cache_dir}")
-    a = arch_of(conf)
+    family = families.of(conf)
+    a = family.arch(conf)
     vocab = a.vocab
     params, sched, t_weights, t_warm, warm = harness.setup(conf, mix, seed)
     reqs = traffic.generate(mix, seed, seconds, vocab)
@@ -194,7 +196,8 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
             breakdown = {"device_ops": red.top_ops(), "idle_gaps":
                          red.idle_gaps()}
         ctx = types.SimpleNamespace(
-            arch=a, counters=delta, summary=summ, window_s=win.window_s,
+            arch=a, family=family, counters=delta, summary=summ,
+            window_s=win.window_s,
             max_slots=mix["serve"]["max_slots"],
             pool_pages=mix["serve"]["pool_tokens"] // harness.PAGE_SIZE,
             prompt_tokens_admitted=admitted_prompt,

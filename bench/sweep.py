@@ -23,8 +23,7 @@ for p in (ROOT, ROOT / "src"):
     if str(p) not in sys.path:
         sys.path.insert(0, str(p))
 
-from bench import harness, run, serving_stats, traffic  # noqa: E402
-from bench.reference import arch_of  # noqa: E402
+from bench import families, harness, run, serving_stats, traffic  # noqa: E402
 
 
 def main() -> int:
@@ -41,7 +40,7 @@ def main() -> int:
     mix = harness.load_json(f"bench/traffic/{cell['traffic']}.json")
     harness.use_cache()
     run.check_devices(cell["chips"], True)
-    vocab = arch_of(conf).vocab
+    vocab = families.arch_of(conf).vocab
     _, sched, _, _, _ = harness.setup(conf, mix, args.seed)
     lim = mix["limits"]
     for rate in args.rates:

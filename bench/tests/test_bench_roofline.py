@@ -2,11 +2,16 @@
 against numbers worked out by hand."""
 import json
 import pathlib
+import types
 
 import pytest
 
-from bench import roofline
-from bench.reference import arch_of
+from bench import roofline as peaks
+from bench.families import arch_of, dense
+
+# the counts moved into the dense family and the peaks stayed; the
+# assertions read both under the name they had before the move
+roofline = types.SimpleNamespace(**{**vars(peaks), **vars(dense)})
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 

@@ -30,8 +30,7 @@ for p in (ROOT, ROOT / "src"):
     if str(p) not in sys.path:
         sys.path.insert(0, str(p))
 
-from bench import harness, serving_stats, traffic  # noqa: E402
-from bench.reference import arch_of  # noqa: E402
+from bench import families, harness, serving_stats, traffic  # noqa: E402
 from bench.run import by_name  # noqa: E402
 
 REQUEST_SPANS = ("queued", "prefill", "held")
@@ -52,7 +51,7 @@ def serve(bench: dict, workload: str, seed: int, seconds: float,
         mix = harness.load_json(f"bench/traffic/{cell['traffic']}.json")
     harness.use_cache()
     _, sched, _, _, _ = harness.setup(conf, mix, seed)
-    reqs = traffic.generate(mix, seed, seconds, arch_of(conf).vocab)
+    reqs = traffic.generate(mix, seed, seconds, families.arch_of(conf).vocab)
     tracer = None
     if spans:
         tracer = Tracer(clock=clock)
